@@ -12,6 +12,7 @@ import time
 
 from repro.data.synthetic import ALIGNED_SCENARIOS, PAPER_METRIC
 from repro.experiments import ExperimentSpec, MethodSpec, sweep
+from repro.launch.compile_cache import use_compile_cache
 
 
 def _by_cell(results):
@@ -112,6 +113,7 @@ def run(quick=True, max_epochs=40, csv=True):
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--max-epochs", type=int, default=40)

@@ -8,6 +8,7 @@ import numpy as np
 
 from repro.core import comm
 from repro.data.synthetic import ALIGNED_SCENARIOS, SPECS
+from repro.launch.compile_cache import use_compile_cache
 
 # paper Table 2 SplitNN epoch statistics are dataset-realization dependent
 # (early stopping); these are the paper's mean round counts for reference
@@ -64,4 +65,5 @@ def run(csv=True):
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     run()

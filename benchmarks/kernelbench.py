@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from repro.kernels import ops
 from repro.kernels.ref import (flash_attention_ref, fused_distill_loss_ref,
                                int8_matmul_ref, mlp2_ref, probe_grad_ref)
+from repro.launch.compile_cache import use_compile_cache
 
 # pinned max-abs-error bound per kernel row (vs the jnp oracle, fp32).
 # lane_mlp/probe/int8 are closed-form identical math — their error is
@@ -163,6 +164,7 @@ def run(csv=True, out_json: str = "BENCH_kernels.json"):
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="BENCH_kernels.json",
                     help="JSON output path ('' to skip)")

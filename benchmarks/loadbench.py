@@ -47,6 +47,7 @@ from repro.data.synthetic import make_dataset
 from repro.data.vertical import make_scenario
 from repro.serve import runtime as rt
 from repro.serve import vfl as sv
+from repro.launch.compile_cache import use_compile_cache
 
 
 def _segment(registry, bundles, scenarios, *, arrivals: str,
@@ -273,6 +274,7 @@ def run(*, tenants: int = 3, requests: int = 2000, rate_rps: float = 400.0,
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--tenants", type=int, default=3)
     ap.add_argument("--requests", type=int, default=2000,

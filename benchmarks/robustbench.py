@@ -48,6 +48,7 @@ from repro.data.vertical import make_scenario
 from repro.robustness import attacks, defense, faults
 from repro.serve import runtime as rt
 from repro.serve import vfl as sv
+from repro.launch.compile_cache import use_compile_cache
 
 SIGMAS = (0.0, 0.5, 2.0, 8.0)
 MONOTONE_TOL = 0.05      # attacks are trained estimators; small jitter ok
@@ -285,6 +286,7 @@ def run(*, epochs: int = 15, requests: int = 1200, rate_rps: float = 300.0,
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--epochs", type=int, default=15)
     ap.add_argument("--requests", type=int, default=1200,

@@ -42,6 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import HBM_BW, ICI_BW, INPUT_SHAPES, PEAK_FLOPS_BF16
+from repro.launch.compile_cache import use_compile_cache
 
 N_LINKS = 3   # ICI links per v5e chip usable concurrently (2D torus + wrap)
 
@@ -58,8 +59,6 @@ def _cost(fn, *args) -> dict:
     writing zeros that would classify every stage as infinitely
     compute-bound)."""
     ca = jax.jit(fn).lower(*args).compile().cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0]
     if not ca or "flops" not in ca or "bytes accessed" not in ca:
         raise RuntimeError(
             f"cost_analysis on backend {jax.default_backend()!r} did not "
@@ -286,6 +285,7 @@ def markdown_table(recs: list) -> str:
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", choices=["vfl", "dryrun"], default="vfl")
     ap.add_argument("--batch", type=int, default=32)

@@ -10,8 +10,11 @@
 import argparse
 import sys
 
+from repro.launch.compile_cache import use_compile_cache
+
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--max-epochs", type=int, default=40)

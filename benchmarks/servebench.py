@@ -39,6 +39,7 @@ from repro.core import pipeline
 from repro.data.synthetic import make_dataset
 from repro.data.vertical import make_scenario
 from repro.serve import vfl as sv
+from repro.launch.compile_cache import use_compile_cache
 
 MAX_BATCH_SHAPES = 6          # acceptance: distinct compiled batch shapes
 MIN_SPEEDUP = 5.0             # acceptance: bucketed vs naive throughput
@@ -175,6 +176,7 @@ def run(*, requests: int = 10_000, max_rows: int = 100, epochs: int = 15,
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=10_000)
     ap.add_argument("--max-rows", type=int, default=100)

@@ -28,14 +28,13 @@ Scale mode (``--scale``) is the million-row device-count sweep: a
 10^6-row x 8-party x multi-seed synthetic vertical partition
 (``data.scale.make_scale_lanes``, built device-resident) trained through
 the mesh-sharded fused lane engine (``train_lanes(..., mesh=...)``) at
-increasing device counts.  Each device count runs in a SUBPROCESS with
-``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (the flag must be
-set before jax initializes), writing one cell; the parent aggregates the
-scaling curve into ``BENCH_scale.json``.  ``--smoke`` shrinks the grid
-for CI.  On a single physical CPU the fake devices share cores, so the
-curve demonstrates the sharding mechanism and its overhead, not a true
-speedup; on real multi-device hosts the same flag-free path shards
-across accelerators.
+increasing device counts.  All counts run in this one process, each on a
+mesh over the first n of ``jax.devices()``; the scaling curve, with the
+device it ran on, goes to ``BENCH_scale.json``.  On the CPU the fake
+host device count (``--xla_force_host_platform_device_count``) is set
+once, before JAX starts, to the largest count; those fake devices share
+cores, so the curve shows the sharding mechanism and its overhead, not a
+speedup.  ``--smoke`` shrinks the grid for CI.
 
 Run:  PYTHONPATH=src python benchmarks/trainbench.py [--rows 4096]
       [--features 30] [--epochs 20] [--batches 32,64,128] [--csv]
@@ -50,10 +49,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import re
-import subprocess
-import sys
-import tempfile
 import time
 
 import jax
@@ -61,6 +56,7 @@ import numpy as np
 
 from repro.core import autoencoder as ae
 from repro.core import training
+from repro.launch.compile_cache import use_compile_cache
 
 
 def _steps_per_sec(params, data, *, batch_size, epochs) -> float:
@@ -335,16 +331,17 @@ def run_sweep(epochs: int = 30, seeds: int = 5, repeats: int = 3,
 # ---------------------------------------------------------------------------
 
 def run_scale_cell(*, devices: int, rows: int, parties: int, seeds: int,
-                   features: int, epochs: int, batch_size: int, dp: int,
-                   cell_out: str) -> dict:
-    """One device count, measured inside the subprocess that owns the
-    matching ``XLA_FLAGS``: generate the lanes device-resident, train all
-    party x seed lanes through the mesh-sharded fused engine, record
-    cold (compile+run) and warm wall clock."""
+                   features: int, epochs: int, batch_size: int,
+                   dp: int) -> dict:
+    """One device count: a lane mesh over the first ``devices`` of
+    ``jax.devices()``, the lanes generated device-resident on it, all
+    party x seed lanes trained through the mesh-sharded fused engine;
+    records cold (compile+run) and warm wall clock."""
     from repro.data.scale import make_scale_lanes
     from repro.launch.mesh import make_lane_mesh
 
-    assert devices % dp == 0, (devices, dp)
+    if devices % dp:
+        raise ValueError(f"--dp {dp} does not divide {devices} devices")
     mesh = make_lane_mesh(lane=devices // dp, data=dp)
     t0 = time.time()
     lanes = make_scale_lanes(rows, parties, n_features=features,
@@ -362,10 +359,9 @@ def run_scale_cell(*, devices: int, rows: int, parties: int, seeds: int,
     warm_s = time.time() - t0
 
     steps = int(sum(r.steps_run for r in results))
-    cell = {
+    return {
         "devices": devices,
-        "jax_device_count": jax.device_count(),
-        "mesh": {"lane": devices // dp, "data": dp},
+        "mesh": dict(mesh.shape),
         "lanes": len(lanes),
         "gen_s": round(gen_s, 3),
         "train_cold_s": round(cold_s, 3),
@@ -376,47 +372,20 @@ def run_scale_cell(*, devices: int, rows: int, parties: int, seeds: int,
         "final_train_loss": float(np.mean([r.train_loss[-1]
                                            for r in results])),
     }
-    with open(cell_out, "w") as fh:
-        json.dump(cell, fh)
-    return cell
-
-
-def _cell_env(devices: int) -> dict:
-    """Child env with exactly one force_host_platform_device_count flag."""
-    env = dict(os.environ)
-    flags = re.sub(r"--xla_force_host_platform_device_count=\d+", "",
-                   env.get("XLA_FLAGS", "")).strip()
-    env["XLA_FLAGS"] = (f"{flags} --xla_force_host_platform_device_count="
-                        f"{devices}").strip()
-    return env
 
 
 def run_scale(*, rows: int = 1_000_000, parties: int = 8, seeds: int = 2,
               features: int = 16, epochs: int = 2, batch_size: int = 8192,
               dp: int = 1, device_counts=(1, 2, 4, 8),
               out_json: str = "BENCH_scale.json", csv: bool = True) -> dict:
-    """Parent of the device-count sweep: one subprocess per device count
-    (``XLA_FLAGS`` must exist before jax initializes, so in-process
-    re-meshing is impossible), aggregated into ``out_json``."""
+    """The device-count sweep, in this one process: each count trains on
+    a mesh over the first n devices, aggregated into ``out_json``."""
+    d0 = jax.devices()[0]
     cells = []
     for n in device_counts:
-        with tempfile.NamedTemporaryFile(suffix=".json", delete=False) as fh:
-            cell_out = fh.name
-        cmd = [sys.executable, os.path.abspath(__file__), "--scale-cell",
-               "--cell-devices", str(n), "--rows", str(rows),
-               "--parties", str(parties), "--scale-seeds", str(seeds),
-               "--features", str(features), "--epochs", str(epochs),
-               "--scale-bs", str(batch_size), "--dp", str(dp),
-               "--cell-out", cell_out]
-        t0 = time.time()
-        proc = subprocess.run(cmd, env=_cell_env(n))
-        if proc.returncode != 0:
-            raise RuntimeError(f"scale cell devices={n} failed "
-                               f"(exit {proc.returncode})")
-        with open(cell_out) as fh:
-            cell = json.load(fh)
-        os.unlink(cell_out)
-        cell["subprocess_s"] = round(time.time() - t0, 3)
+        cell = run_scale_cell(devices=n, rows=rows, parties=parties,
+                              seeds=seeds, features=features, epochs=epochs,
+                              batch_size=batch_size, dp=dp)
         cells.append(cell)
         if csv:
             print(f"trainbench/scale/dev{n},"
@@ -427,6 +396,8 @@ def run_scale(*, rows: int = 1_000_000, parties: int = 8, seeds: int = 2,
 
     base = cells[0]["train_warm_s"]
     payload = {
+        "device": {"platform": d0.platform, "kind": d0.device_kind,
+                   "count": jax.device_count()},
         "grid": {"rows": rows, "parties": parties, "seeds": seeds,
                  "lanes": parties * seeds, "features": features,
                  "epochs": epochs, "batch_size": batch_size, "dp": dp,
@@ -444,7 +415,18 @@ def run_scale(*, rows: int = 1_000_000, parties: int = 8, seeds: int = 2,
     return payload
 
 
+def _fake_cpu_devices(n: int) -> None:
+    """Give the CPU backend ``n`` devices unless ``XLA_FLAGS`` already
+    sets a count.  Must run before JAX starts its backends; on an
+    accelerator the flag only shapes the unused host platform."""
+    flags = os.environ.get("XLA_FLAGS", "")
+    if "xla_force_host_platform_device_count" not in flags:
+        os.environ["XLA_FLAGS"] = (
+            f"{flags} --xla_force_host_platform_device_count={n}").strip()
+
+
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--rows", type=int, default=4096)
     ap.add_argument("--features", type=int, default=30)
@@ -476,22 +458,12 @@ def main() -> None:
     ap.add_argument("--dp", type=int, default=1,
                     help="row-sharding (data axis) devices per lane group")
     ap.add_argument("--scale-out", default="BENCH_scale.json")
-    ap.add_argument("--scale-cell", action="store_true",
-                    help="internal: run one device-count cell in this "
-                         "process")
-    ap.add_argument("--cell-devices", type=int, default=1)
-    ap.add_argument("--cell-out", default="")
     args = ap.parse_args()
-    if args.scale_cell:
-        run_scale_cell(devices=args.cell_devices, rows=args.rows,
-                       parties=args.parties or 8, seeds=args.scale_seeds,
-                       features=args.features, epochs=args.epochs or 2,
-                       batch_size=args.scale_bs or 8192, dp=args.dp,
-                       cell_out=args.cell_out)
-    elif args.scale:
+    if args.scale:
         smoke = args.smoke
         devs = ([int(d) for d in args.devices_list.split(",") if d]
                 or ([1, 2] if smoke else [1, 2, 4, 8]))
+        _fake_cpu_devices(max(devs))
         run_scale(
             rows=args.rows if args.rows != 4096 else
             (16_384 if smoke else 1_000_000),

@@ -27,7 +27,9 @@ from typing import Iterable, List
 STATIC_ATTRS = {"shape", "ndim", "dtype", "size", "itemsize", "nbytes"}
 # calls that yield static Python values regardless of their arguments
 STATIC_CALLS = {"len", "range", "isinstance", "hasattr", "getattr", "type",
-                "str", "repr", "id", "callable"}
+                "str", "repr", "id", "callable",
+                # jax's queries of the runtime answer in Python values
+                "jax.default_backend", "jax.devices", "jax.device_count"}
 # host-library namespaces: their results live on the host (R001's problem,
 # not taint's — don't keep propagating device taint through them)
 HOST_PREFIXES = ("numpy.", "math.", "scipy.")

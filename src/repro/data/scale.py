@@ -74,8 +74,10 @@ def make_scale_party(n_rows: int, *, n_features: int, n_latent: int = 8,
     device in ``block_rows`` chunks.  Block b's latent key depends only on
     ``(seed, b)`` — NOT on the party — so all parties of one scenario see
     the same latent z per row: a genuine vertical partition.  With a
-    ``mesh`` carrying a ``data`` axis that divides ``n_rows``, the
-    finished array is placed row-sharded across it."""
+    ``mesh`` whose ``data`` axis has several devices and divides
+    ``n_rows``, the finished array is placed row-sharded across it (a
+    one-device data axis leaves it where it was made, rather than
+    replicating it over the lane axis)."""
     mix = _party_mix(n_latent, n_features, party)
     blocks = []
     done = 0
@@ -93,7 +95,7 @@ def make_scale_party(n_rows: int, *, n_features: int, n_latent: int = 8,
     if mesh is not None and "data" in mesh.axis_names:
         from jax.sharding import NamedSharding, PartitionSpec as P
         sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-        if n_rows % sizes["data"] == 0:
+        if sizes["data"] > 1 and n_rows % sizes["data"] == 0:
             x = jax.device_put(x, NamedSharding(mesh, P("data")))
     return x
 

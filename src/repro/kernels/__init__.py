@@ -2,18 +2,3 @@
 # for compute hot-spots the paper itself optimizes with a custom
 # kernel. Leave this package empty if the paper has none.
 
-
-def tpu_compiler_params(**kwargs):
-    """Version-portable ``pltpu.*CompilerParams`` constructor: jax <= 0.4.x
-    ships ``TPUCompilerParams``, newer releases renamed it ``CompilerParams``.
-    Raises a descriptive error instead of a NoneType crash inside
-    ``pallas_call`` when neither exists."""
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams", None)
-    if cls is None:
-        raise ImportError(
-            "jax.experimental.pallas.tpu exposes neither CompilerParams nor "
-            "TPUCompilerParams; this jax version is unsupported by "
-            "repro.kernels")
-    return cls(**kwargs)

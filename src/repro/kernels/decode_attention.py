@@ -22,8 +22,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 NEG_INF = -1e30
 
 
@@ -96,7 +94,7 @@ def decode_attention(q, k, v, slot_pos, pos, *, window: int = 0,
             pltpu.VMEM((1,), jnp.float32),
             pltpu.VMEM((1, hd), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, slot_pos, pos[None].astype(jnp.int32))

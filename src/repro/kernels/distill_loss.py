@@ -18,6 +18,12 @@ tiling): for row cotangents g_i,
     d a_i    =  g_i * lam * dis_i
 This is what lets ``use_kernel=True`` train under ``jax.value_and_grad``
 in the scan engine (the raw ``pallas_call`` has no VJP rule).
+
+Per-row vectors (mask, losses, their cotangents) cross the kernel
+boundary as ``(rows, 1)`` columns: Mosaic requires each block's last two
+dimensions to be (8, 128)-aligned or to span the array's, and a 1-D
+``(block_b,)`` block stops meeting that once ``jax.vmap`` prepends the
+lane axis of the lane engine.
 """
 from __future__ import annotations
 
@@ -34,33 +40,33 @@ def _kernel(x_ref, xh_ref, z_ref, zt_ref, m_ref, o_ref, *, lam: float,
     xh = xh_ref[...].astype(jnp.float32)
     z = z_ref[...].astype(jnp.float32)
     zt = zt_ref[...].astype(jnp.float32)
-    mask = m_ref[...].astype(jnp.float32)
-    rec = jnp.mean(jnp.square(x - xh), axis=-1)
+    mask = m_ref[...].astype(jnp.float32)                       # (bb, 1)
+    rec = jnp.mean(jnp.square(x - xh), axis=-1, keepdims=True)
     diff = z - zt
-    dis = (jnp.mean(jnp.abs(diff), axis=-1) if kind == "mae"
-           else jnp.mean(jnp.square(diff), axis=-1))
+    dis = (jnp.mean(jnp.abs(diff), axis=-1, keepdims=True) if kind == "mae"
+           else jnp.mean(jnp.square(diff), axis=-1, keepdims=True))
     o_ref[...] = rec + lam * mask * dis
 
 
 def _bwd_kernel(g_ref, x_ref, xh_ref, z_ref, zt_ref, m_ref,
                 dx_ref, dz_ref, dm_ref, *, lam: float, kind: str):
-    g = g_ref[...].astype(jnp.float32)
+    g = g_ref[...].astype(jnp.float32)                          # (bb, 1)
     x = x_ref[...].astype(jnp.float32)
     xh = xh_ref[...].astype(jnp.float32)
     z = z_ref[...].astype(jnp.float32)
     zt = zt_ref[...].astype(jnp.float32)
-    mask = m_ref[...].astype(jnp.float32)
+    mask = m_ref[...].astype(jnp.float32)                       # (bb, 1)
     D = x.shape[-1]
     M = z.shape[-1]
     diff = z - zt
-    dx_ref[...] = (g[:, None] * (2.0 / D)) * (x - xh)
+    dx_ref[...] = (g * (2.0 / D)) * (x - xh)
     if kind == "mae":
-        dis = jnp.mean(jnp.abs(diff), axis=-1)
+        dis = jnp.mean(jnp.abs(diff), axis=-1, keepdims=True)
         ddis = jnp.sign(diff) / M
     else:
-        dis = jnp.mean(jnp.square(diff), axis=-1)
+        dis = jnp.mean(jnp.square(diff), axis=-1, keepdims=True)
         ddis = 2.0 * diff / M
-    dz_ref[...] = (g * lam * mask)[:, None] * ddis
+    dz_ref[...] = (g * lam * mask) * ddis
     dm_ref[...] = g * lam * dis
 
 
@@ -75,7 +81,8 @@ def _rows_fwd_call(x, x_hat, z, z_t, mask, lam, kind, block_b, interpret):
     B, D = x.shape
     M = z.shape[1]
     pad = (-B) % block_b
-    x, x_hat, z, z_t, mask = _pad_rows((x, x_hat, z, z_t, mask), pad)
+    x, x_hat, z, z_t, mask = _pad_rows((x, x_hat, z, z_t, mask[:, None]),
+                                       pad)
     Bp = B + pad
     out = pl.pallas_call(
         functools.partial(_kernel, lam=lam, kind=kind),
@@ -85,45 +92,46 @@ def _rows_fwd_call(x, x_hat, z, z_t, mask, lam, kind, block_b, interpret):
             pl.BlockSpec((block_b, D), lambda i: (i, 0)),
             pl.BlockSpec((block_b, M), lambda i: (i, 0)),
             pl.BlockSpec((block_b, M), lambda i: (i, 0)),
-            pl.BlockSpec((block_b,), lambda i: (i,)),
+            pl.BlockSpec((block_b, 1), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((block_b,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((Bp,), jnp.float32),
+        out_specs=pl.BlockSpec((block_b, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((Bp, 1), jnp.float32),
         interpret=interpret,
     )(x, x_hat, z, z_t, mask)
-    return out[:B]
+    return out[:B, 0]
 
 
 def _rows_bwd_call(g, x, x_hat, z, z_t, mask, lam, kind, block_b, interpret):
     B, D = x.shape
     M = z.shape[1]
     pad = (-B) % block_b
-    g, x, x_hat, z, z_t, mask = _pad_rows((g, x, x_hat, z, z_t, mask), pad)
+    g, x, x_hat, z, z_t, mask = _pad_rows(
+        (g[:, None], x, x_hat, z, z_t, mask[:, None]), pad)
     Bp = B + pad
     dx, dz, dm = pl.pallas_call(
         functools.partial(_bwd_kernel, lam=lam, kind=kind),
         grid=(Bp // block_b,),
         in_specs=[
-            pl.BlockSpec((block_b,), lambda i: (i,)),
+            pl.BlockSpec((block_b, 1), lambda i: (i, 0)),
             pl.BlockSpec((block_b, D), lambda i: (i, 0)),
             pl.BlockSpec((block_b, D), lambda i: (i, 0)),
             pl.BlockSpec((block_b, M), lambda i: (i, 0)),
             pl.BlockSpec((block_b, M), lambda i: (i, 0)),
-            pl.BlockSpec((block_b,), lambda i: (i,)),
+            pl.BlockSpec((block_b, 1), lambda i: (i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((block_b, D), lambda i: (i, 0)),
             pl.BlockSpec((block_b, M), lambda i: (i, 0)),
-            pl.BlockSpec((block_b,), lambda i: (i,)),
+            pl.BlockSpec((block_b, 1), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((Bp, D), jnp.float32),
             jax.ShapeDtypeStruct((Bp, M), jnp.float32),
-            jax.ShapeDtypeStruct((Bp,), jnp.float32),
+            jax.ShapeDtypeStruct((Bp, 1), jnp.float32),
         ],
         interpret=interpret,
     )(g, x, x_hat, z, z_t, mask)
-    return dx[:B], dz[:B], dm[:B]
+    return dx[:B], dz[:B], dm[:B, 0]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))

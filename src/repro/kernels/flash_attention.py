@@ -20,8 +20,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 NEG_INF = -1e30
 
 
@@ -99,7 +97,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((block_q,), jnp.float32),        # denominator
             pltpu.VMEM((block_q, hd), jnp.float32),     # output acc
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf)

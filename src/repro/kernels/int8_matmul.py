@@ -29,7 +29,8 @@ _SELU_SCALE = 1.0507009873554804934193349852946
 
 
 def _selu(a):
-    return _SELU_SCALE * jnp.where(a > 0, a, _SELU_ALPHA * jnp.expm1(a))
+    # exp(a) - 1: the form Mosaic lowers (see kernels.lane_mlp._selu)
+    return _SELU_SCALE * jnp.where(a > 0, a, _SELU_ALPHA * (jnp.exp(a) - 1.0))
 
 
 def _int8_kernel(x_ref, wq_ref, scale_ref, b_ref, o_ref, *, act):

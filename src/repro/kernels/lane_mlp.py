@@ -31,6 +31,11 @@ Weight gradients are written as PER-TILE partials (leading grid axis)
 and reduced outside the kernel: an in-kernel accumulator over
 ``pl.program_id`` would alias across the vmap-prepended lane axis,
 per-tile partials are batching-safe by construction.
+
+Mosaic requires each block's last two dimensions to be multiples of
+(8, 128) or to equal the array's.  So biases enter as ``(1, width)`` rows
+and bias partials leave as ``(tiles, 1, width)``: both stay legal when
+``jax.vmap`` prepends the lane axis.
 """
 from __future__ import annotations
 
@@ -40,17 +45,19 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-# jax.nn.selu constants: selu(x) = SCALE * where(x > 0, x, ALPHA*expm1(x))
+# jax.nn.selu constants: selu(x) = SCALE * where(x > 0, x, ALPHA*(e^x - 1))
 _SELU_ALPHA = 1.6732632423543772848170429916717
 _SELU_SCALE = 1.0507009873554804934193349852946
 
 
 def _selu(a):
-    return _SELU_SCALE * jnp.where(a > 0, a, _SELU_ALPHA * jnp.expm1(a))
+    # Mosaic lowers exp but not the fused e^a - 1 primitive; the two forms
+    # differ by at most one float32 ulp of 1 (~1.2e-7) on the a <= 0 branch
+    return _SELU_SCALE * jnp.where(a > 0, a, _SELU_ALPHA * (jnp.exp(a) - 1.0))
 
 
 def _dselu(a):
-    # exact derivative of the expm1 form autodiff differentiates
+    # exact derivative of the SELU that autodiff differentiates
     return _SELU_SCALE * jnp.where(a > 0, 1.0, _SELU_ALPHA * jnp.exp(a))
 
 
@@ -80,10 +87,10 @@ def _bwd_kernel(g_ref, x_ref, a1_ref, a2_ref, w0_ref, w1_ref,
     g2 = g * _dselu(a2_ref[...].astype(jnp.float32)) if final_act else g
     h1 = _selu(a1)
     dw1_ref[0] = jnp.dot(h1.T, g2, preferred_element_type=jnp.float32)
-    db1_ref[0] = jnp.sum(g2, axis=0)
+    db1_ref[0] = jnp.sum(g2, axis=0, keepdims=True)
     g1 = jnp.dot(g2, w1.T, preferred_element_type=jnp.float32) * _dselu(a1)
     dw0_ref[0] = jnp.dot(x.T, g1, preferred_element_type=jnp.float32)
-    db0_ref[0] = jnp.sum(g1, axis=0)
+    db0_ref[0] = jnp.sum(g1, axis=0, keepdims=True)
     dx_ref[...] = jnp.dot(g1, w0.T, preferred_element_type=jnp.float32)
 
 
@@ -106,7 +113,7 @@ def _fwd_call(x, w0, b0, w1, b1, final_act, block_b, interpret):
         grid=(Bp // block_b,),
         in_specs=[
             pl.BlockSpec((block_b, din), lambda i: (i, 0)),
-            full((din, h)), full((h,)), full((h, dz)), full((dz,)),
+            full((din, h)), full((1, h)), full((h, dz)), full((1, dz)),
         ],
         out_specs=[
             pl.BlockSpec((block_b, dz), lambda i: (i, 0)),
@@ -119,7 +126,7 @@ def _fwd_call(x, w0, b0, w1, b1, final_act, block_b, interpret):
             jax.ShapeDtypeStruct((Bp, dz), jnp.float32),
         ],
         interpret=interpret,
-    )(x, w0, b0, w1, b1)
+    )(x, w0, b0.reshape(1, h), w1, b1.reshape(1, dz))
     return out[:B], a1[:B], a2[:B]
 
 
@@ -144,21 +151,21 @@ def _bwd_call(g, x, a1, a2, w0, w1, final_act, block_b, interpret):
         out_specs=[
             pl.BlockSpec((block_b, din), lambda i: (i, 0)),
             pl.BlockSpec((1, din, h), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, h), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, h), lambda i: (i, 0, 0)),
             pl.BlockSpec((1, h, dz), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, dz), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, dz), lambda i: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((Bp, din), jnp.float32),
             jax.ShapeDtypeStruct((nt, din, h), jnp.float32),
-            jax.ShapeDtypeStruct((nt, h), jnp.float32),
+            jax.ShapeDtypeStruct((nt, 1, h), jnp.float32),
             jax.ShapeDtypeStruct((nt, h, dz), jnp.float32),
-            jax.ShapeDtypeStruct((nt, dz), jnp.float32),
+            jax.ShapeDtypeStruct((nt, 1, dz), jnp.float32),
         ],
         interpret=interpret,
     )(g, x, a1, a2, w0, w1)
-    return (dx[:B], jnp.sum(dw0p, axis=0), jnp.sum(db0p, axis=0),
-            jnp.sum(dw1p, axis=0), jnp.sum(db1p, axis=0))
+    return (dx[:B], jnp.sum(dw0p, axis=0), jnp.sum(db0p, axis=(0, 1)),
+            jnp.sum(dw1p, axis=0), jnp.sum(db1p, axis=(0, 1)))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))

@@ -1,8 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On the CPU container the kernels execute via ``interpret=True`` (Pallas
-TPU lowering needs real TPUs); on TPU set ``repro.kernels.ops.INTERPRET =
-False`` (or leave the default auto-detection) for compiled execution.
+Each call picks its mode from the backend it runs on: compiled through
+Mosaic on a TPU, Pallas interpret mode on the CPU (where the tests run,
+under ``JAX_PLATFORMS=cpu``), and an error on any other backend, so no
+path falls back to interpreting quietly.
 """
 from __future__ import annotations
 
@@ -12,7 +13,16 @@ import jax.numpy as jnp
 from repro.kernels import distill_loss as _dl
 from repro.kernels import flash_attention as _fa
 
-INTERPRET = jax.default_backend() != "tpu"
+
+def _interpret() -> bool:
+    """True on the CPU, False on a TPU; other backends have no kernel path."""
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(f"the Pallas kernels target TPU (compiled) or CPU "
+                       f"(interpret mode); no path for backend {platform!r}")
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
@@ -25,7 +35,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     bq = min(block_q, S)
     bk = min(block_k, S)
     out = _fa.flash_attention(qt, kt, vt, causal=causal, window=window,
-                              block_q=bq, block_k=bk, interpret=INTERPRET)
+                              block_q=bq, block_k=bk, interpret=_interpret())
     return jnp.swapaxes(out, 1, 2)
 
 
@@ -33,7 +43,7 @@ def fused_distill_rows(x, x_hat, z, z_t, mask, *, lam: float = 0.01,
                        kind: str = "mse"):
     """Per-row Eq. 5 losses (differentiable; closed-form custom VJP)."""
     return _dl.fused_distill_rows(x, x_hat, z, z_t, mask, lam=lam, kind=kind,
-                                  interpret=INTERPRET)
+                                  interpret=_interpret())
 
 
 def fused_distill_loss(x, x_hat, z, z_t, mask, *, lam: float = 0.01,
@@ -48,7 +58,7 @@ def fused_mlp2(x, w0, b0, w1, b1, *, final_act: bool = False,
     VJP).  Lane axis enters the kernel grid via ``jax.vmap``."""
     from repro.kernels import lane_mlp as _lm
     return _lm.fused_mlp2(x, w0, b0, w1, b1, final_act=final_act,
-                          block_b=block_b, interpret=INTERPRET)
+                          block_b=block_b, interpret=_interpret())
 
 
 def fused_lane_mlp2(xs, w0s, b0s, w1s, b1s, live, *,
@@ -58,7 +68,7 @@ def fused_lane_mlp2(xs, w0s, b0s, w1s, b1s, live, *,
     from repro.kernels import lane_mlp as _lm
     return _lm.fused_lane_mlp2(xs, w0s, b0s, w1s, b1s, live,
                                final_act=final_act, block_b=block_b,
-                               interpret=INTERPRET)
+                               interpret=_interpret())
 
 
 def probe_grad_step(w, b, x, y, rw, *, l2: float = 1e-4,
@@ -66,7 +76,7 @@ def probe_grad_step(w, b, x, y, rw, *, l2: float = 1e-4,
     """Fused weighted softmax-CE probe step: (loss, dW, db) in one pass."""
     from repro.kernels import probe as _pr
     return _pr.probe_grad_step(w, b, x, y, rw, l2=l2, block_b=block_b,
-                               interpret=INTERPRET)
+                               interpret=_interpret())
 
 
 def int8_matmul(x, w_q, scale, b, *, act: str = "none",
@@ -75,7 +85,7 @@ def int8_matmul(x, w_q, scale, b, *, act: str = "none",
     fused SELU) — the quantized serving path's GEMM."""
     from repro.kernels import int8_matmul as _i8
     return _i8.int8_matmul(x, w_q, scale, b, act=act, block_b=block_b,
-                           interpret=INTERPRET)
+                           interpret=_interpret())
 
 
 def decode_attention(q, k, v, slot_pos, pos, *, window: int = 0,
@@ -89,5 +99,5 @@ def decode_attention(q, k, v, slot_pos, pos, *, window: int = 0,
     kf = jnp.swapaxes(k, 1, 2).reshape(B * H, W, hd)
     vf = jnp.swapaxes(v, 1, 2).reshape(B * H, W, hd)
     out = _da.decode_attention(qf, kf, vf, slot_pos, pos, window=window,
-                               block_w=min(block_w, W), interpret=INTERPRET)
+                               block_w=min(block_w, W), interpret=_interpret())
     return out.reshape(B, H, hd)

@@ -20,8 +20,12 @@ Per-tile partials (loss, dW, db) are written on the leading grid axis
 and reduced outside the kernel — batching-safe by construction, like the
 lane-MLP backward.  Row weights arrive PRE-normalized (the wrapper
 divides by ``max(sum(rw), 1)``) so tiles need no global reduction; the
-L2 term is added outside.  Matches ``kernels.ref.probe_grad_ref``, i.e.
-the autodiff gradient of ``classifier._weighted_logreg_loss``.
+L2 term is added outside.  Row vectors (labels, weights, bias) enter as
+2-D columns/rows and partials leave as ``(tiles, 1, width)``, so every
+block's last two dimensions are (8, 128)-aligned or span the array's, as
+Mosaic requires with or without the vmapped fold axis.  Matches
+``kernels.ref.probe_grad_ref``, i.e. the autodiff gradient of
+``classifier._weighted_logreg_loss``.
 """
 from __future__ import annotations
 
@@ -36,21 +40,21 @@ def _probe_kernel(x_ref, y_ref, rwn_ref, w_ref, b_ref,
                   loss_ref, dw_ref, db_ref):
     x = x_ref[...].astype(jnp.float32)
     w = w_ref[...].astype(jnp.float32)
-    rwn = rwn_ref[...].astype(jnp.float32)
+    rwn = rwn_ref[...].astype(jnp.float32)                     # (bb, 1)
     logits = jnp.dot(x, w, preferred_element_type=jnp.float32) \
         + b_ref[...].astype(jnp.float32)
     # stable logsumexp + softmax sharing one max/exp evaluation
     m = jnp.max(logits, axis=-1, keepdims=True)
     e = jnp.exp(logits - m)
     se = jnp.sum(e, axis=-1, keepdims=True)
-    lse = jnp.log(se[:, 0]) + m[:, 0]
+    lse = jnp.log(se) + m
     onehot = (jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
-              == y_ref[...][:, None]).astype(jnp.float32)
-    gold = jnp.sum(logits * onehot, axis=-1)
-    loss_ref[0, 0] = jnp.sum((lse - gold) * rwn)
-    g = (e / se - onehot) * rwn[:, None]
+              == y_ref[...]).astype(jnp.float32)
+    gold = jnp.sum(logits * onehot, axis=-1, keepdims=True)
+    loss_ref[0] = jnp.sum((lse - gold) * rwn, axis=0, keepdims=True)
+    g = (e / se - onehot) * rwn
     dw_ref[0] = jnp.dot(x.T, g, preferred_element_type=jnp.float32)
-    db_ref[0] = jnp.sum(g, axis=0)
+    db_ref[0] = jnp.sum(g, axis=0, keepdims=True)
 
 
 def _probe_call(x, y, rwn, w, b, block_b: int, interpret: bool):
@@ -69,23 +73,23 @@ def _probe_call(x, y, rwn, w, b, block_b: int, interpret: bool):
         grid=(nt,),
         in_specs=[
             pl.BlockSpec((block_b, d), lambda i: (i, 0)),
-            pl.BlockSpec((block_b,), lambda i: (i,)),
-            pl.BlockSpec((block_b,), lambda i: (i,)),
-            full((d, c)), full((c,)),
+            pl.BlockSpec((block_b, 1), lambda i: (i, 0)),
+            pl.BlockSpec((block_b, 1), lambda i: (i, 0)),
+            full((d, c)), full((1, c)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, 1), lambda i: (i, 0, 0)),
             pl.BlockSpec((1, d, c), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, c), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, c), lambda i: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((nt, 1), jnp.float32),
+            jax.ShapeDtypeStruct((nt, 1, 1), jnp.float32),
             jax.ShapeDtypeStruct((nt, d, c), jnp.float32),
-            jax.ShapeDtypeStruct((nt, c), jnp.float32),
+            jax.ShapeDtypeStruct((nt, 1, c), jnp.float32),
         ],
         interpret=interpret,
-    )(x, y, rwn, w, b)
-    return jnp.sum(lossp), jnp.sum(dwp, axis=0), jnp.sum(dbp, axis=0)
+    )(x, y[:, None], rwn[:, None], w, b.reshape(1, c))
+    return jnp.sum(lossp), jnp.sum(dwp, axis=0), jnp.sum(dbp, axis=(0, 1))
 
 
 @functools.partial(jax.jit, static_argnames=("l2", "block_b", "interpret"))

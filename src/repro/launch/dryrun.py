@@ -132,19 +132,10 @@ def lower_combo(arch: str, shape_name: str, *, multi_pod: bool,
     return cfg, shape, mesh, compiled, t_lower, t_compile
 
 
-def _cost_dict(compiled) -> dict:
-    """compiled.cost_analysis() compat: jax<=0.4.x returns a one-dict list
-    per program, newer versions return the dict itself."""
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
 def analyze(arch, shape_name, cfg, compiled, mesh, t_lower, t_compile,
             multi_pod, objective):
     mem = compiled.memory_analysis()
-    cost = _cost_dict(compiled)
+    cost = compiled.cost_analysis()
     hlo = hlo_analysis.analyze_text(compiled.as_text())
     n_chips = int(np.prod(mesh.devices.shape))
     rec = {
@@ -211,7 +202,7 @@ def main():
         args.arch, args.shape, multi_pod=args.multi_pod,
         objective=args.objective, cfg=cfg)
     print(compiled.memory_analysis())
-    print({k: v for k, v in _cost_dict(compiled).items()
+    print({k: v for k, v in compiled.cost_analysis().items()
            if k in ("flops", "bytes accessed")})
     rec = analyze(args.arch, args.shape, cfg, compiled, mesh, t_lower,
                   t_compile, args.multi_pod, args.objective)

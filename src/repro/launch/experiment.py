@@ -19,6 +19,7 @@ import sys
 import time
 
 from repro.experiments import ExperimentSpec, MethodSpec, sweep, tidy
+from repro.launch.compile_cache import use_compile_cache
 
 
 def smoke_spec() -> ExperimentSpec:
@@ -55,6 +56,7 @@ def _summary_table(records: list) -> str:
 
 
 def main(argv=None) -> int:
+    use_compile_cache()
     ap = argparse.ArgumentParser(
         description="run a declarative ExperimentSpec end-to-end")
     ap.add_argument("spec", nargs="?", default=None,

@@ -7,15 +7,17 @@ jax device state — required because the dry-run must set
 initializes, while tests and benches must see one device.
 
 Every constructor validates the requested shape against
-``jax.device_count()`` up front: an oversized ``jax.make_mesh`` otherwise
-fails deep inside jax with a reshape error that names neither the mesh nor
-the fix.  The ``ValueError`` raised here names both.
+``jax.devices()`` up front: an oversized ``jax.make_mesh`` otherwise
+fails deep inside jax with a reshape error that names neither the mesh
+nor the fix.  The ``ValueError`` raised here names both.  A mesh smaller
+than the device list spans its first devices.
 """
 from __future__ import annotations
 
 import math
 
 import jax
+from jax.sharding import AxisType
 
 
 def _checked_mesh(shape: tuple, axes: tuple):
@@ -24,14 +26,18 @@ def _checked_mesh(shape: tuple, axes: tuple):
             raise ValueError(f"mesh axis {ax!r} must be a positive int, "
                              f"got {n!r}")
     need = math.prod(shape)
-    have = jax.device_count()
+    devices = jax.devices()
+    have = len(devices)
     if need > have:
         raise ValueError(
             f"mesh {dict(zip(axes, shape))} needs {need} devices but only "
             f"{have} are available — on CPU, fake host devices with "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={need} "
             "(set BEFORE jax initializes)")
-    return jax.make_mesh(shape, axes)
+    # Auto axes: shardings propagate from the placed inputs, as the lane
+    # engine and the dry-run expect (jax.make_mesh defaults to Explicit)
+    return jax.make_mesh(shape, axes, devices=devices[:need],
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -51,5 +57,6 @@ def make_lane_mesh(lane: int = 1, data: int = 1):
     shards independent lanes across devices, the ``data`` axis optionally
     shards rows within a lane (``shard_rows=True``).  Axis names line up
     with the logical-axis policy (``sharding.policy``: ``"lane"`` ->
-    ``("lane",)``, ``"dp"`` -> ``("data",)``)."""
+    ``("lane",)``, ``"dp"`` -> ``("data",)``).  The mesh spans the first
+    ``lane * data`` of ``jax.devices()``."""
     return _checked_mesh((lane, data), ("lane", "data"))

@@ -37,10 +37,12 @@ import numpy as np
 from repro.core import multiparty, pipeline
 from repro.data.synthetic import make_dataset
 from repro.data.vertical import make_scenario
+from repro.launch.compile_cache import use_compile_cache
 from repro.serve import vfl as sv
 
 
 def main(argv=None) -> int:
+    use_compile_cache()
     ap = argparse.ArgumentParser(
         description="online serving for a trained APC-VFL model")
     ap.add_argument("--dataset", default="bcw")

@@ -100,6 +100,9 @@ def test_r002_silent_on_static_and_shape_branches():
         def f(x, *, mode: str = "a"):
             if mode == "a":
                 return x
+            platform = jax.default_backend()
+            if platform == "cpu" or jax.device_count() > 1:
+                x = x * 2.0
             if x.ndim == 1:
                 return -x
             n = len([k for k in x.shape])

@@ -14,6 +14,22 @@ from repro.kernels.ref import (flash_attention_ref, fused_distill_loss_ref,
                                mlp2_ref, probe_grad_ref, ssd_chunk_ref)
 
 
+@pytest.mark.parametrize("backend,interpret", [("cpu", True),
+                                               ("tpu", False)])
+def test_ops_mode_follows_the_backend_at_call_time(monkeypatch, backend,
+                                                   interpret):
+    """The wrappers compile on a TPU and interpret on the CPU, decided
+    when called, not when ``kernels.ops`` was imported."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert ops._interpret() is interpret
+
+
+def test_ops_refuses_a_backend_without_a_kernel_path(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        ops._interpret()
+
+
 @pytest.mark.parametrize("S,hd,bq,bk", [
     (128, 64, 64, 64),
     (256, 64, 128, 64),

@@ -51,6 +51,12 @@ def test_make_lane_mesh_too_many_devices_names_the_fix():
     assert f"xla_force_host_platform_device_count={want}" in msg
 
 
+def test_make_lane_mesh_spans_first_devices():
+    """A mesh spans the first ``lane * data`` of ``jax.devices()``."""
+    assert list(meshlib.make_lane_mesh(lane=1).devices.flat) \
+        == [jax.devices()[0]]
+
+
 def test_make_local_mesh_too_many_devices():
     with pytest.raises(ValueError, match="needs"):
         meshlib.make_local_mesh(data=jax.device_count() * 2)

@@ -21,6 +21,31 @@ def scenario():
     return make_scenario(ds, n_active_features=3, n_aligned=200, seed=3)
 
 
+@pytest.fixture
+def cache_dir_config():
+    was = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_compile_cache_defaults_to_the_checkout(monkeypatch,
+                                                cache_dir_config):
+    from repro.launch.compile_cache import use_compile_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    want = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), ".jax_cache")
+    assert use_compile_cache() == want
+    assert jax.config.jax_compilation_cache_dir == want
+
+
+def test_compile_cache_env_wins(monkeypatch, tmp_path, cache_dir_config):
+    from repro.launch.compile_cache import use_compile_cache
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert use_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before   # nothing set
+
+
 def test_single_communication_round(scenario):
     """Headline claim: APC-VFL needs exactly ONE data exchange, and its
     size follows Eq. 6 exactly."""

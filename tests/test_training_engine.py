@@ -53,15 +53,20 @@ def _trace_runs():
 
 def test_engine_matches_stored_trace():
     trace = json.loads(TRACE_PATH.read_text())
+    # the initial parameters come from jax.random, whose bit layout may
+    # change between jax releases: a mismatch names both versions
+    versions = (f"trace made with jax {trace['jax_version']}, "
+                f"running jax {jax.__version__}")
     for name, (params, data, kw) in _trace_runs().items():
         r = training.train(params, data, ae.recon_loss, **kw)
-        want = trace[name]
-        assert r.epochs_run == want["epochs_run"], name
-        assert r.steps_run == want["steps_run"], name
+        want = trace["runs"][name]
+        msg = f"{name} ({versions})"
+        assert r.epochs_run == want["epochs_run"], msg
+        assert r.steps_run == want["steps_run"], msg
         np.testing.assert_allclose(r.train_loss, want["train_loss"],
-                                   rtol=2e-3, atol=1e-5, err_msg=name)
+                                   rtol=2e-3, atol=1e-5, err_msg=msg)
         np.testing.assert_allclose(r.val_loss, want["val_loss"],
-                                   rtol=2e-3, atol=1e-5, err_msg=name)
+                                   rtol=2e-3, atol=1e-5, err_msg=msg)
 
 
 def test_scan_drops_remainder():
