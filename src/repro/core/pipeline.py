@@ -53,6 +53,7 @@ from repro.core import comm
 from repro.core import distill
 from repro.core import training
 from repro.core.psi import psi
+from repro.core.spans import span
 from repro.data.vertical import VFLScenario
 from repro.experiments.results import RunResult
 
@@ -230,42 +231,48 @@ def run_apcvfl_replicated(scenarios, *, seeds, lam: float = HP.lam,
 
     if not ablation:
         # --- Step 1: 2S g1 lanes (active + passive per seed) ---------------
-        lanes = []
-        for sc, s, (k1, k2, _, _) in zip(scs, seeds, keys):
-            lanes.append(training.LaneSpec(
-                ae.init_autoencoder(k1, ae.table3_encoder(
-                    "g1_active", sc.active.x.shape[1])),
-                {"x": sc.active.x}, s))
-            lanes.append(training.LaneSpec(
-                ae.init_autoencoder(k2, ae.table3_encoder(
-                    "g1_passive", sc.passive.x.shape[1])),
-                {"x": sc.passive.x}, s + 1))
-        g1 = training.train_lanes(lanes, ae.make_masked_recon_loss(use_kernel),
-                                  **train_kw)
+        with span("g1"):
+            lanes = []
+            for sc, s, (k1, k2, _, _) in zip(scs, seeds, keys):
+                lanes.append(training.LaneSpec(
+                    ae.init_autoencoder(k1, ae.table3_encoder(
+                        "g1_active", sc.active.x.shape[1])),
+                    {"x": sc.active.x}, s))
+                lanes.append(training.LaneSpec(
+                    ae.init_autoencoder(k2, ae.table3_encoder(
+                        "g1_passive", sc.passive.x.shape[1])),
+                    {"x": sc.passive.x}, s + 1))
+            g1 = training.train_lanes(
+                lanes, ae.make_masked_recon_loss(use_kernel), **train_kw)
+
+        # --- the exchange: aligned passive latents, device-resident --------
+        with span("exchange"):
+            zjs, zps = [], []
+            for i, (sc, ch, (_, idx_a, idx_p)) in enumerate(
+                    zip(scs, channels, psis)):
+                ra, rp = g1[2 * i], g1[2 * i + 1]
+                epochs[i]["g1_active"] = ra.epochs_run
+                epochs[i]["g1_passive"] = rp.epochs_run
+                za_al = ae.encode(ra.params, jnp.asarray(sc.active.x[idx_a]))
+                zp_al = ae.encode(rp.params,
+                                  jnp.asarray(sc.passive.x[idx_p]))
+                zp_al = comm.exchange_array(ch, "step1/Z_passive_aligned",
+                                            zp_al, transform=exchanges[i],
+                                            seed=seeds[i])
+                zps.append(zp_al)
+                zjs.append(jnp.concatenate([za_al, zp_al],
+                                           axis=1).astype(jnp.float32))
 
         # --- Step 2: S g2 lanes on device-resident joint latents -----------
-        zjs, zps = [], []
-        for i, (sc, ch, (_, idx_a, idx_p)) in enumerate(
-                zip(scs, channels, psis)):
-            ra, rp = g1[2 * i], g1[2 * i + 1]
-            epochs[i]["g1_active"] = ra.epochs_run
-            epochs[i]["g1_passive"] = rp.epochs_run
-            za_al = ae.encode(ra.params, jnp.asarray(sc.active.x[idx_a]))
-            zp_al = ae.encode(rp.params, jnp.asarray(sc.passive.x[idx_p]))
-            zp_al = comm.exchange_array(ch, "step1/Z_passive_aligned",
-                                        zp_al, transform=exchanges[i],
-                                        seed=seeds[i])
-            zps.append(zp_al)
-            zjs.append(jnp.concatenate([za_al, zp_al],
-                                       axis=1).astype(jnp.float32))
-        g2 = training.train_lanes(
-            [training.LaneSpec(
-                ae.init_autoencoder(k3, ae.table3_encoder("g2",
-                                                          zj.shape[1])),
-                {"x": zj}, s + 2)
-             for zj, s, (_, _, k3, _) in zip(zjs, seeds, keys)],
-            ae.make_masked_recon_loss(use_kernel), **train_kw)
-        zts = [ae.encode(r2.params, zj) for r2, zj in zip(g2, zjs)]
+        with span("g2"):
+            g2 = training.train_lanes(
+                [training.LaneSpec(
+                    ae.init_autoencoder(k3, ae.table3_encoder(
+                        "g2", zj.shape[1])),
+                    {"x": zj}, s + 2)
+                 for zj, s, (_, _, k3, _) in zip(zjs, seeds, keys)],
+                ae.make_masked_recon_loss(use_kernel), **train_kw)
+            zts = [ae.encode(r2.params, zj) for r2, zj in zip(g2, zjs)]
         m2 = zts[0].shape[1]
         for i, r2 in enumerate(g2):
             epochs[i]["g2"] = r2.epochs_run
@@ -275,32 +282,37 @@ def run_apcvfl_replicated(scenarios, *, seeds, lam: float = HP.lam,
         zps = [None] * S
 
     # --- Step 3: S g3 distillation lanes ------------------------------------
-    g3_lanes = []
-    for sc, s, (_, _, _, k4), zt, (_, idx_a, _) in zip(scs, seeds, keys,
-                                                       zts, psis):
-        xa = sc.active.x
-        z_teacher = jnp.zeros((len(xa), m2), jnp.float32)
-        mask = jnp.zeros((len(xa),), jnp.float32)
-        if not ablation:
-            z_teacher = z_teacher.at[idx_a].set(zt)
-            mask = mask.at[idx_a].set(1.0)
-        w3 = ae.table3_encoder("g3", xa.shape[1])
-        assert w3[-1] == m2, "M3 == M2: dimensional consistency (Sec. 4.3)"
-        g3_lanes.append(training.LaneSpec(
-            ae.init_autoencoder(k4, w3),
-            {"x": xa, "z_teacher": z_teacher, "aligned": mask}, s + 3))
-    g3 = training.train_lanes(
-        g3_lanes, distill.make_lanes_loss(lam, kind, use_kernel=use_kernel),
-        **train_kw)
+    with span("g3"):
+        g3_lanes = []
+        for sc, s, (_, _, _, k4), zt, (_, idx_a, _) in zip(
+                scs, seeds, keys, zts, psis):
+            xa = sc.active.x
+            z_teacher = jnp.zeros((len(xa), m2), jnp.float32)
+            mask = jnp.zeros((len(xa),), jnp.float32)
+            if not ablation:
+                z_teacher = z_teacher.at[idx_a].set(zt)
+                mask = mask.at[idx_a].set(1.0)
+            w3 = ae.table3_encoder("g3", xa.shape[1])
+            assert w3[-1] == m2, ("M3 == M2: dimensional consistency "
+                                  "(Sec. 4.3)")
+            g3_lanes.append(training.LaneSpec(
+                ae.init_autoencoder(k4, w3),
+                {"x": xa, "z_teacher": z_teacher, "aligned": mask}, s + 3))
+        g3 = training.train_lanes(
+            g3_lanes, distill.make_lanes_loss(lam, kind,
+                                              use_kernel=use_kernel),
+            **train_kw)
 
     # --- Step 4: classifier probes, all S seeds' folds as one doubly-
     # vmapped lane dispatch (S x k probe fits, one compile + one sync).
     # Per-seed metrics match kfold_cv(z, ..., seed=s) within lane-engine
     # tolerance (tests/test_replicas.py pins the band).
-    z_alls = [ae.encode(r3.params, jnp.asarray(sc.active.x))
-              for sc, r3 in zip(scs, g3)]
-    metrics_list = clf.kfold_cv_many(
-        z_alls, [sc.active.y for sc in scs], scs[0].n_classes, seeds=seeds)
+    with span("probe"):
+        z_alls = [ae.encode(r3.params, jnp.asarray(sc.active.x))
+                  for sc, r3 in zip(scs, g3)]
+        metrics_list = clf.kfold_cv_many(
+            z_alls, [sc.active.y for sc in scs], scs[0].n_classes,
+            seeds=seeds)
     results = []
     data_rounds = 0 if ablation else comm.APCVFL_ROUNDS
     for i, (s, ch, r3, ep, metrics) in enumerate(zip(seeds, channels, g3,

@@ -10,6 +10,8 @@ import hashlib
 
 import numpy as np
 
+from repro.core.spans import span
+
 
 def _hash_ids(ids: np.ndarray, salt: bytes) -> dict:
     hashed = {hashlib.sha256(salt + int(i).to_bytes(8, "little")).digest():
@@ -33,17 +35,19 @@ def psi(ids_a: np.ndarray, ids_b: np.ndarray, *, salt: bytes = b"psi",
         channel=None):
     """Returns (aligned_ids sorted, idx_a, idx_b) such that
     ids_a[idx_a] == ids_b[idx_b] == aligned_ids."""
-    ha = _hash_ids(ids_a, salt)
-    hb = _hash_ids(ids_b, salt)
-    if channel is not None:
-        # a = active party by convention: its hashes flow OUT (downlink),
-        # the peer's reply flows back toward it (uplink)
-        channel.send("psi/hashes_a", len(ids_a) * 32, direction="downlink")
-        channel.send("psi/hashes_b", len(ids_b) * 32, direction="uplink")
-    common = sorted(ha[h] for h in (set(ha) & set(hb)))
-    common = np.asarray(common, dtype=np.int64)
-    pos_a = id_positions(ids_a)
-    pos_b = id_positions(ids_b)
-    idx_a = np.asarray([pos_a[int(c)] for c in common], dtype=np.int64)
-    idx_b = np.asarray([pos_b[int(c)] for c in common], dtype=np.int64)
+    with span("psi"):
+        ha = _hash_ids(ids_a, salt)
+        hb = _hash_ids(ids_b, salt)
+        if channel is not None:
+            # a = active party by convention: its hashes flow OUT
+            # (downlink), the peer's reply flows back toward it (uplink)
+            channel.send("psi/hashes_a", len(ids_a) * 32,
+                         direction="downlink")
+            channel.send("psi/hashes_b", len(ids_b) * 32, direction="uplink")
+        common = sorted(ha[h] for h in (set(ha) & set(hb)))
+        common = np.asarray(common, dtype=np.int64)
+        pos_a = id_positions(ids_a)
+        pos_b = id_positions(ids_b)
+        idx_a = np.asarray([pos_a[int(c)] for c in common], dtype=np.int64)
+        idx_b = np.asarray([pos_b[int(c)] for c in common], dtype=np.int64)
     return common, idx_a, idx_b

@@ -113,6 +113,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import padding
+from repro.core.spans import span
 from repro.optim.adam import paper_adam
 
 
@@ -696,38 +697,44 @@ def train_lanes(specs: Sequence[LaneSpec], loss_fn: Callable, *,
     host_parts = []               # (epochs, tls, vls) per group, in-flight
     for idxs in _lane_groups(specs):
         gspecs = [specs[i] for i in idxs]
-        (params, opt_state, base_keys, tr, val, n_tr, nb, bs,
-         shapes) = _prep_lanes(gspecs, batch_size=global_bs,
-                               val_frac=val_frac, lr=lr)
-        n_batches = int(nb.max())
-        uniform = bool((nb == nb[0]).all())
-        nb_dev = jnp.asarray(nb, jnp.int32)
-        n_tr_dev = jnp.asarray(n_tr, jnp.int32)
-        live0 = jnp.ones((len(idxs),), bool)
+        with span("lanes.prep"):
+            (params, opt_state, base_keys, tr, val, n_tr, nb, bs,
+             shapes) = _prep_lanes(gspecs, batch_size=global_bs,
+                                   val_frac=val_frac, lr=lr)
+            n_batches = int(nb.max())
+            uniform = bool((nb == nb[0]).all())
+            nb_dev = jnp.asarray(nb, jnp.int32)
+            n_tr_dev = jnp.asarray(n_tr, jnp.int32)
+            live0 = jnp.ones((len(idxs),), bool)
         if mesh is not None:
-            (params, opt_state, base_keys, tr, val, n_tr_dev, nb_dev,
-             live0) = _shard_lanes(mesh, params, opt_state, base_keys, tr,
-                                   val, n_tr_dev, nb_dev, live0,
-                                   shard_rows=shard_rows)
-        best_params, epochs, tls, vls = engine(
-            params, opt_state, base_keys, tr, val, n_tr_dev, nb_dev, live0,
-            n_batches=n_batches, batch_size=bs, max_epochs=max_epochs,
-            patience=patience, uniform=uniform)
+            with span("lanes.shard"):
+                (params, opt_state, base_keys, tr, val, n_tr_dev, nb_dev,
+                 live0) = _shard_lanes(mesh, params, opt_state, base_keys,
+                                       tr, val, n_tr_dev, nb_dev, live0,
+                                       shard_rows=shard_rows)
+        with span("lanes.launch"):
+            best_params, epochs, tls, vls = engine(
+                params, opt_state, base_keys, tr, val, n_tr_dev, nb_dev,
+                live0, n_batches=n_batches, batch_size=bs,
+                max_epochs=max_epochs, patience=patience, uniform=uniform)
         launched.append((idxs, gspecs, best_params, shapes, nb))
         host_parts.append((epochs, tls, vls))
     # the single host sync of the fit, coalesced over every shape group
     # (dead padding lanes sliced away)
-    host_parts = jax.device_get(host_parts)
+    with span("lanes.sync"):
+        host_parts = jax.device_get(host_parts)
 
     results: List[TrainResult] = [None] * K  # type: ignore[list-item]
-    for (idxs, gspecs, best_params, shapes, nb), (epochs, tls, vls) in zip(
-            launched, host_parts):
-        stripped = _strip_lane_params(gspecs, best_params, shapes)
-        for j, i in enumerate(idxs):
-            e = int(epochs[j])
-            results[i] = TrainResult(stripped[j], e, e * int(nb[j]),
-                                     [float(t) for t in tls[:e, j]],
-                                     [float(v) for v in vls[:e, j]])
+    with span("lanes.unstack"):
+        for (idxs, gspecs, best_params, shapes, nb), parts in zip(
+                launched, host_parts):
+            epochs, tls, vls = parts
+            stripped = _strip_lane_params(gspecs, best_params, shapes)
+            for j, i in enumerate(idxs):
+                e = int(epochs[j])
+                results[i] = TrainResult(stripped[j], e, e * int(nb[j]),
+                                         [float(t) for t in tls[:e, j]],
+                                         [float(v) for v in vls[:e, j]])
     return results
 
 
