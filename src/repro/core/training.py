@@ -557,13 +557,11 @@ def _prep_lanes(specs: Sequence[LaneSpec], *, batch_size: int,
     # --- padded-stack, built on device (no host round-trip) ---------------
     tr = padding.pad_stack(tr_list)
     val = padding.pad_stack(val_list)
-    shapes = [[np.shape(l) for l in jax.tree.leaves(sp.params)]
-              for sp in specs]
     params = padding.pad_stack([sp.params for sp in specs])
     opt_state = paper_adam(lr).init(params)
     opt_state = opt_state._replace(step=jnp.zeros((K,), jnp.int32))
     base_keys = jnp.stack([jax.random.PRNGKey(sp.seed) for sp in specs])
-    return params, opt_state, base_keys, tr, val, n_tr, nb, bs, shapes
+    return params, opt_state, base_keys, tr, val, n_tr, nb, bs
 
 
 def _shard_lanes(mesh, params, opt_state, base_keys, tr, val, n_tr, nb,
@@ -611,16 +609,26 @@ def _shard_lanes(mesh, params, opt_state, base_keys, tr, val, n_tr, nb,
     return params, opt_state, base_keys, tr, val, n_tr, nb, live0
 
 
-def _strip_lane_params(specs, best_params, shapes):
-    """Unstack the best-val params and strip each lane's zero padding."""
+# every live lane's leaves out of a shape group's stack in ONE dispatch,
+# not two eager programs a leaf a lane; module-scoped like _FOLD_KEYS, so
+# it traces once per leaf shapes, lane count and sharding.  Dead
+# mesh-padding lanes (``i >= k``) are never emitted.  On a mesh the
+# outputs come back replicated over it, as eager ``l[i]`` does: callers
+# concatenate lanes' encodings, which arrays on different chips would break.
+@partial(jax.jit, static_argnums=1)
+def _unstack_lanes(leaves, k):
+    """Lanes ``0 .. k-1`` of every stacked leaf, lane by lane."""
+    return tuple(tuple(l[i] for l in leaves) for i in range(k))
+
+
+def _unstack_lane_params(specs, best_params):
+    """One params tree per spec out of the group's stacked best-val params.
+    A group's lanes share every shape (``_lane_groups``), so the stack
+    holds no padding to strip."""
     treedef = jax.tree.structure(specs[0].params)
-    leaves = jax.tree.leaves(best_params)
-    out = []
-    for i in range(len(specs)):
-        pl = [l[i][tuple(slice(0, s) for s in shp)]
-              for l, shp in zip(leaves, shapes[i])]
-        out.append(jax.tree.unflatten(treedef, pl))
-    return out
+    lanes = _unstack_lanes(tuple(jax.tree.leaves(best_params)),
+                           len(specs))
+    return [jax.tree.unflatten(treedef, pl) for pl in lanes]
 
 
 def _lane_groups(specs: Sequence[LaneSpec]):
@@ -680,8 +688,9 @@ def train_lanes(specs: Sequence[LaneSpec], loss_fn: Callable, *,
     ``data`` axis (the large-row regime).  Sharded or not, the same jitted
     engine runs the same computation — parity is exact.
 
-    Returns one ``TrainResult`` per lane with padding stripped from the
-    best-val params and histories truncated at that lane's stop epoch."""
+    Returns one ``TrainResult`` per lane: its best-val params, unstacked
+    from the group in one dispatch, and histories truncated at that lane's
+    stop epoch."""
     K = len(specs)
     # global batch-size clamp (the ungrouped engine's bs): computed over
     # ALL lanes so per-group _prep_lanes clamps to exactly this value
@@ -693,14 +702,14 @@ def train_lanes(specs: Sequence[LaneSpec], loss_fn: Callable, *,
     global_bs = max(min(batch_size, min(n_tr_all)), 1)
 
     engine = get_lanes_fit_engine(loss_fn, lr=lr)
-    launched = []                 # (idxs, gspecs, best_params, shapes, nb)
+    launched = []                 # (idxs, gspecs, best_params, nb)
     host_parts = []               # (epochs, tls, vls) per group, in-flight
     for idxs in _lane_groups(specs):
         gspecs = [specs[i] for i in idxs]
         with span("lanes.prep"):
-            (params, opt_state, base_keys, tr, val, n_tr, nb, bs,
-             shapes) = _prep_lanes(gspecs, batch_size=global_bs,
-                                   val_frac=val_frac, lr=lr)
+            (params, opt_state, base_keys, tr, val, n_tr, nb,
+             bs) = _prep_lanes(gspecs, batch_size=global_bs,
+                               val_frac=val_frac, lr=lr)
             n_batches = int(nb.max())
             uniform = bool((nb == nb[0]).all())
             nb_dev = jnp.asarray(nb, jnp.int32)
@@ -717,7 +726,7 @@ def train_lanes(specs: Sequence[LaneSpec], loss_fn: Callable, *,
                 params, opt_state, base_keys, tr, val, n_tr_dev, nb_dev,
                 live0, n_batches=n_batches, batch_size=bs,
                 max_epochs=max_epochs, patience=patience, uniform=uniform)
-        launched.append((idxs, gspecs, best_params, shapes, nb))
+        launched.append((idxs, gspecs, best_params, nb))
         host_parts.append((epochs, tls, vls))
     # the single host sync of the fit, coalesced over every shape group
     # (dead padding lanes sliced away)
@@ -726,13 +735,13 @@ def train_lanes(specs: Sequence[LaneSpec], loss_fn: Callable, *,
 
     results: List[TrainResult] = [None] * K  # type: ignore[list-item]
     with span("lanes.unstack"):
-        for (idxs, gspecs, best_params, shapes, nb), parts in zip(
-                launched, host_parts):
+        for (idxs, gspecs, best_params, nb), parts in zip(launched,
+                                                          host_parts):
             epochs, tls, vls = parts
-            stripped = _strip_lane_params(gspecs, best_params, shapes)
+            lane_params = _unstack_lane_params(gspecs, best_params)
             for j, i in enumerate(idxs):
                 e = int(epochs[j])
-                results[i] = TrainResult(stripped[j], e, e * int(nb[j]),
+                results[i] = TrainResult(lane_params[j], e, e * int(nb[j]),
                                          [float(t) for t in tls[:e, j]],
                                          [float(v) for v in vls[:e, j]])
     return results
@@ -768,9 +777,9 @@ def train_lanes_epochwise(specs: Sequence[LaneSpec], loss_fn: Callable, *,
 def _train_lanes_epochwise_group(specs, loss_fn, *, batch_size, max_epochs,
                                  patience, lr, val_frac):
     K = len(specs)
-    (params, opt_state, base_keys, tr, val, n_tr, nb, bs,
-     shapes) = _prep_lanes(specs, batch_size=batch_size, val_frac=val_frac,
-                           lr=lr)
+    (params, opt_state, base_keys, tr, val, n_tr, nb,
+     bs) = _prep_lanes(specs, batch_size=batch_size, val_frac=val_frac,
+                       lr=lr)
     n_batches = int(nb.max())
     best_params = jax.tree.map(jnp.copy, params)
     engine = get_lanes_engine(loss_fn, lr=lr)
@@ -809,8 +818,8 @@ def _train_lanes_epochwise_group(specs, loss_fn, *, batch_size, max_epochs,
         if not live.any():
             break
 
-    stripped = _strip_lane_params(specs, best_params, shapes)
-    return [TrainResult(stripped[i], int(epochs_run[i]),
+    lane_params = _unstack_lane_params(specs, best_params)
+    return [TrainResult(lane_params[i], int(epochs_run[i]),
                         int(epochs_run[i] * nb[i]), tl_hist[i], vl_hist[i])
             for i in range(K)]
 
