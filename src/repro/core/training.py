@@ -59,19 +59,22 @@ stage into S x K lanes through the very same engine (``core.pipeline``'s
   and stacked along a leading lane axis (zero rows/cols feed on zero
   inputs and receive zero gradients, so each lane's real sub-block evolves
   exactly as it would unpadded);
-* every data array is zero-padded to the max row count / trailing width
-  and stacked likewise, staying on device throughout (jax-array inputs —
-  e.g. encoder outputs of an earlier protocol stage — are padded and
-  stacked without a host round-trip); when padding is present the loss
-  must consume the ``mask`` (real-feature columns) and ``row_w`` (real-row
-  weights) entries the engine adds to each batch — see
+* the lanes of one stack share their train row count (``_prep_lanes``
+  raises otherwise; ``_lane_groups`` stacks only lanes of one data
+  shape), so each epoch's batches are sliced straight from the lane's
+  permutation of those rows; only the trailing feature width is
+  zero-padded, and the stack stays on device throughout (jax-array
+  inputs — e.g. encoder outputs of an earlier protocol stage — are padded
+  and stacked without a host round-trip); when widths differ the loss
+  must consume the ``mask`` (real-feature columns) entry the engine adds
+  to each batch, beside an all-ones ``row_w`` — see
   ``autoencoder.masked_recon_loss``.  Equal-shape lanes (the seed-replica
   case) need no masking: losses that ignore the extra keys see exactly
   the batches ``train`` would feed them;
 * each lane keeps its own host-side train/val split, PRNG stream, Adam
-  state and step budget (``n_batches_i = n_tr_i // bs``); the shared scan
-  runs ``max_i n_batches_i`` steps and a per-lane step mask freezes params
-  past a lane's own budget;
+  state and step budget (``n_batches = n_tr // bs``; zero for a dead
+  mesh-padding lane), and a per-lane step mask freezes params past a
+  lane's own budget;
 * early stopping is a per-lane ``live`` mask (mirroring the masked-loss
   trick in ``distill.make_loss``): converged lanes keep stepping on
   frozen params so the batch shape stays static, and the outer scan's
@@ -79,12 +82,12 @@ stage into S x K lanes through the very same engine (``core.pipeline``'s
   stopped.
 
 The shared batch size is clamped to the SMALLEST lane's train split so
-every lane runs at least one step per epoch.  For a lane whose row count
-equals the padded maximum, the engine draws the IDENTICAL device
-permutation as ``train`` (same fold_in key); when additionally
-``batch_size <= min_i n_tr_i`` (no cross-lane clamping), that lane's
-results match the sequential path to float tolerance — the parity tests in
-``tests/test_train_many.py`` and ``tests/test_replicas.py`` pin this.
+every lane runs at least one step per epoch.  Every lane draws the
+IDENTICAL device permutation as ``train`` (same fold_in key); when
+additionally ``batch_size <= min_i n_tr_i`` (no cross-lane clamping), a
+lane's results match the sequential path to float tolerance — the parity
+tests in ``tests/test_train_many.py`` and ``tests/test_replicas.py`` pin
+this.
 
 Mesh sharding (``train_lanes(..., mesh=...)``)
 ----------------------------------------------
@@ -283,20 +286,17 @@ def _build_lanes_fit_engine(loss_fn: Callable, lr: float):
 
     @partial(jax.jit, static_argnames=("n_batches", "batch_size",
                                        "max_epochs", "patience", "uniform"))
-    def run_fit_k(params, opt_state, base_keys, tr, val, n_tr, nb, live0, *,
+    def run_fit_k(params, opt_state, base_keys, tr, val, nb, live0, *,
                   n_batches, batch_size, max_epochs, patience,
                   uniform=False):
         L = base_keys.shape[0]
 
-        def lane_epoch(p, s, key, live_p, tr_p, val_p, n_tr_p, nb_p):
-            n_max = tr_p["x"].shape[0]
-            perm = jax.random.permutation(key, n_max)
-            # stable-partition real rows (< n_tr_p) to the front: for an
-            # unpadded lane this is exactly the solo engine's permutation,
-            # so the two paths draw identical mini-batches
-            order = perm[jnp.argsort(perm >= n_tr_p, stable=True)]
-            idx = order[: n_batches * batch_size].reshape(n_batches,
-                                                          batch_size)
+        def lane_epoch(p, s, key, live_p, tr_p, val_p, nb_p):
+            # every lane of the stack has these rows (``_prep_lanes``), so
+            # this is the solo engine's permutation and its mini-batches
+            perm = jax.random.permutation(key, tr_p["x"].shape[0])
+            idx = perm[: n_batches * batch_size].reshape(n_batches,
+                                                         batch_size)
 
             def step(carry, xs):
                 p_, s_ = carry
@@ -337,7 +337,7 @@ def _build_lanes_fit_engine(loss_fn: Callable, lr: float):
             p, s, best_p, best_v, since, live, epochs = carry
             keys = jax.vmap(jax.random.fold_in, (0, None))(base_keys, epoch)
             p, s, tl, vl = jax.vmap(lane_epoch)(p, s, keys, live, tr, val,
-                                                n_tr, nb)
+                                                nb)
             epochs = epochs + live.astype(jnp.int32)
             # the epochwise lanes loop's host bookkeeping, as traced values
             improved = live & (vl < best_v - 1e-6)
@@ -484,17 +484,14 @@ def _build_many_engine(loss_fn: Callable, lr: float):
 
     @partial(jax.jit, static_argnames=("n_batches", "batch_size"),
              donate_argnums=(0, 1))
-    def run_epoch_k(params, opt_state, keys, tr, val, n_tr, nb, live, *,
+    def run_epoch_k(params, opt_state, keys, tr, val, nb, live, *,
                     n_batches, batch_size):
-        def one(p, s, key, tr_p, val_p, n_tr_p, nb_p, live_p):
-            n_max = tr_p["x"].shape[0]
-            perm = jax.random.permutation(key, n_max)
-            # stable-partition real rows (< n_tr_p) to the front: for an
-            # unpadded lane this is exactly the solo engine's permutation,
-            # so the two paths draw identical mini-batches
-            order = perm[jnp.argsort(perm >= n_tr_p, stable=True)]
-            idx = order[: n_batches * batch_size].reshape(n_batches,
-                                                          batch_size)
+        def one(p, s, key, tr_p, val_p, nb_p, live_p):
+            # every lane of the stack has these rows (``_prep_lanes``), so
+            # this is the solo engine's permutation and its mini-batches
+            perm = jax.random.permutation(key, tr_p["x"].shape[0])
+            idx = perm[: n_batches * batch_size].reshape(n_batches,
+                                                         batch_size)
 
             def step(carry, xs):
                 p, s = carry
@@ -516,8 +513,7 @@ def _build_many_engine(loss_fn: Callable, lr: float):
             tl = jnp.sum(losses) / jnp.maximum(nb_p, 1)
             return p, s, tl, loss_fn(p, val_p)
 
-        return jax.vmap(one)(params, opt_state, keys, tr, val, n_tr, nb,
-                             live)
+        return jax.vmap(one)(params, opt_state, keys, tr, val, nb, live)
 
     return run_epoch_k
 
@@ -525,7 +521,9 @@ def _build_many_engine(loss_fn: Callable, lr: float):
 def _prep_lanes(specs: Sequence[LaneSpec], *, batch_size: int,
                 val_frac: float, lr: float):
     """Per-lane host split + padded-stack upload shared by the fused and
-    epochwise lane paths."""
+    epochwise lane paths.  The lanes of one stack must share their train
+    row count: the engines draw each epoch's batches from a permutation of
+    the stack's rows, which would reach padded rows of a shorter lane."""
     K = len(specs)
     assert K >= 1
     for sp in specs:
@@ -533,21 +531,25 @@ def _prep_lanes(specs: Sequence[LaneSpec], *, batch_size: int,
             raise ValueError("train_lanes: every LaneSpec.data needs an "
                              "'x' feature array (sizes the rows and the "
                              "real-feature mask)")
+    rows = [len(next(iter(sp.data.values()))) for sp in specs]
+    n_tr = {n - max(int(n * val_frac), 1) for n in rows}
+    if len(n_tr) > 1:
+        raise ValueError(f"train_lanes: one stack's lanes have unequal "
+                         f"train rows {sorted(n_tr)}; stack only lanes of "
+                         f"one _lane_groups group")
+    n_tr = n_tr.pop()
 
     # --- per-lane split: host-side indices, device-side gather ------------
-    tr_list, val_list, n_tr_l = [], [], []
-    for sp in specs:
-        n = len(next(iter(sp.data.values())))
+    tr_list, val_list = [], []
+    for sp, n in zip(specs, rows):
         split = np.random.RandomState(sp.seed).permutation(n)
-        n_val = max(int(n * val_frac), 1)
+        n_val = n - n_tr
         vi, ti = split[:n_val], split[n_val:]
         dev = {k: jnp.asarray(v) for k, v in sp.data.items()}
         val_list.append({k: v[vi] for k, v in dev.items()})
         tr_list.append({k: v[ti] for k, v in dev.items()})
-        n_tr_l.append(len(ti))
-    n_tr = np.asarray(n_tr_l)
-    bs = max(min(batch_size, int(n_tr.min())), 1)
-    nb = n_tr // bs                       # per-lane step budget per epoch
+    bs = max(min(batch_size, n_tr), 1)
+    nb = np.full((K,), n_tr // bs)        # per-lane step budget per epoch
 
     for t, v in zip(tr_list, val_list):
         t["mask"] = jnp.ones((t["x"].shape[1],), jnp.float32)
@@ -561,15 +563,16 @@ def _prep_lanes(specs: Sequence[LaneSpec], *, batch_size: int,
     opt_state = paper_adam(lr).init(params)
     opt_state = opt_state._replace(step=jnp.zeros((K,), jnp.int32))
     base_keys = jnp.stack([jax.random.PRNGKey(sp.seed) for sp in specs])
-    return params, opt_state, base_keys, tr, val, n_tr, nb, bs
+    return params, opt_state, base_keys, tr, val, nb, bs
 
 
-def _shard_lanes(mesh, params, opt_state, base_keys, tr, val, n_tr, nb,
-                 live0, *, shard_rows: bool):
+def _shard_lanes(mesh, params, opt_state, base_keys, tr, val, *lane_vecs,
+                 shard_rows: bool):
     """Pad the lane axis to a mesh multiple with dead lanes and
-    ``device_put`` every stacked input with policy-resolved shardings.
-    Returns the inputs device-parallel; the engine itself is unchanged
-    (computation follows data)."""
+    ``device_put`` every stacked input, and each per-lane vector in
+    ``lane_vecs`` (step budgets, live flags), with policy-resolved
+    shardings.  Returns the inputs device-parallel in the order given; the
+    engine itself is unchanged (computation follows data)."""
     from jax.sharding import NamedSharding
 
     from repro.sharding import policy
@@ -587,8 +590,8 @@ def _shard_lanes(mesh, params, opt_state, base_keys, tr, val, n_tr, nb,
         # dead lanes: zero params/data, live=False, zero step budget
         return padding.pad_to(a, (Lp,) + a.shape[1:])
 
-    (params, opt_state, base_keys, tr, val, n_tr, nb, live0) = jax.tree.map(
-        grow, (params, opt_state, base_keys, tr, val, n_tr, nb, live0))
+    (params, opt_state, base_keys, tr, val, lane_vecs) = jax.tree.map(
+        grow, (params, opt_state, base_keys, tr, val, lane_vecs))
 
     def put(a, *, rows=False):
         axes = ("lane",)
@@ -601,12 +604,12 @@ def _shard_lanes(mesh, params, opt_state, base_keys, tr, val, n_tr, nb,
 
     params = jax.tree.map(put, params)
     opt_state = jax.tree.map(put, opt_state)
-    base_keys, n_tr, nb, live0 = (put(a) for a in (base_keys, n_tr, nb,
-                                                   live0))
+    base_keys = put(base_keys)
+    lane_vecs = tuple(put(a) for a in lane_vecs)
     # "mask" is per-feature, not per-row; everything else shards rows
     tr = {k: put(v, rows=shard_rows and k != "mask") for k, v in tr.items()}
     val = {k: put(v, rows=shard_rows and k != "mask") for k, v in val.items()}
-    return params, opt_state, base_keys, tr, val, n_tr, nb, live0
+    return (params, opt_state, base_keys, tr, val) + lane_vecs
 
 
 # every live lane's leaves out of a shape group's stack in ONE dispatch,
@@ -707,23 +710,22 @@ def train_lanes(specs: Sequence[LaneSpec], loss_fn: Callable, *,
     for idxs in _lane_groups(specs):
         gspecs = [specs[i] for i in idxs]
         with span("lanes.prep"):
-            (params, opt_state, base_keys, tr, val, n_tr, nb,
+            (params, opt_state, base_keys, tr, val, nb,
              bs) = _prep_lanes(gspecs, batch_size=global_bs,
                                val_frac=val_frac, lr=lr)
             n_batches = int(nb.max())
             uniform = bool((nb == nb[0]).all())
             nb_dev = jnp.asarray(nb, jnp.int32)
-            n_tr_dev = jnp.asarray(n_tr, jnp.int32)
             live0 = jnp.ones((len(idxs),), bool)
         if mesh is not None:
             with span("lanes.shard"):
-                (params, opt_state, base_keys, tr, val, n_tr_dev, nb_dev,
+                (params, opt_state, base_keys, tr, val, nb_dev,
                  live0) = _shard_lanes(mesh, params, opt_state, base_keys,
-                                       tr, val, n_tr_dev, nb_dev, live0,
+                                       tr, val, nb_dev, live0,
                                        shard_rows=shard_rows)
         with span("lanes.launch"):
             best_params, epochs, tls, vls = engine(
-                params, opt_state, base_keys, tr, val, n_tr_dev, nb_dev,
+                params, opt_state, base_keys, tr, val, nb_dev,
                 live0, n_batches=n_batches, batch_size=bs,
                 max_epochs=max_epochs, patience=patience, uniform=uniform)
         launched.append((idxs, gspecs, best_params, nb))
@@ -777,14 +779,13 @@ def train_lanes_epochwise(specs: Sequence[LaneSpec], loss_fn: Callable, *,
 def _train_lanes_epochwise_group(specs, loss_fn, *, batch_size, max_epochs,
                                  patience, lr, val_frac):
     K = len(specs)
-    (params, opt_state, base_keys, tr, val, n_tr, nb,
+    (params, opt_state, base_keys, tr, val, nb,
      bs) = _prep_lanes(specs, batch_size=batch_size, val_frac=val_frac,
                        lr=lr)
     n_batches = int(nb.max())
     best_params = jax.tree.map(jnp.copy, params)
     engine = get_lanes_engine(loss_fn, lr=lr)
     nb_dev = jnp.asarray(nb, jnp.int32)
-    n_tr_dev = jnp.asarray(n_tr, jnp.int32)
 
     best_val = np.full((K,), np.inf)
     since = np.zeros((K,), np.int64)
@@ -796,7 +797,7 @@ def _train_lanes_epochwise_group(specs, loss_fn, *, batch_size, max_epochs,
     for epoch in range(max_epochs):
         keys = _FOLD_KEYS(base_keys, epoch)  # all lanes' keys, one dispatch
         params, opt_state, tl, vl = engine(
-            params, opt_state, keys, tr, val, n_tr_dev, nb_dev,
+            params, opt_state, keys, tr, val, nb_dev,
             jnp.asarray(live), n_batches=n_batches, batch_size=bs)
         tl = np.asarray(tl)
         vl = np.asarray(vl)               # the single host sync of the epoch
