@@ -8,10 +8,10 @@ and ~2.5x at bs=128 on a 2-core CPU container (PR 1); the engine's
 semantics are now pinned by the stored-trace oracle
 (``tests/data/train_trace.json``) instead of a live parity run.
 
-K-party mode (``--kparty``) benchmarks the batched multi-party engine
-(``training.train_many``: all K parties' g1 stages as ONE vmapped scan —
-one dispatch + one host sync per epoch total) against K sequential
-``training.train`` calls (K dispatch chains, K syncs per epoch), for
+K-party mode (``--kparty``) benchmarks the lane engine on K parties
+(``training.train_lanes``: all K parties' g1 stages as lanes of ONE
+vmapped scan — one dispatch + one host sync per fit) against K sequential
+``training.train`` calls (each a lane of one: K dispatches, K syncs), for
 K in {2, 4, 8} with uneven per-party feature widths (exercising the
 padded-stack layout).
 
@@ -94,13 +94,13 @@ def _kparty_specs(k: int, rows: int, features: int):
         x = np.random.RandomState(i).randn(rows, d).astype(np.float32)
         params = ae.init_autoencoder(jax.random.PRNGKey(i),
                                      ae.table3_encoder("g1_passive", d))
-        specs.append(training.PartySpec(params, {"x": x}, seed=i))
+        specs.append(training.LaneSpec(params, {"x": x}, seed=i))
     return specs
 
 
 def run_kparty(rows: int = 2048, features: int = 24, epochs: int = 10,
                batch_size: int = 32, ks=(2, 4, 8), csv: bool = True) -> list:
-    """train_many (one vmapped scan for all K parties) vs K sequential
+    """train_lanes (one vmapped scan for all K parties) vs K sequential
     training.train calls, total steps/s across parties."""
     rows_out = []
     for k in ks:
@@ -112,7 +112,7 @@ def run_kparty(rows: int = 2048, features: int = 24, epochs: int = 10,
                                    seed=s.seed, **kw) for s in specs]
 
         def batched():
-            return training.train_many(specs, ae.masked_recon_loss, **kw)
+            return training.train_lanes(specs, ae.masked_recon_loss, **kw)
 
         for fn in (seq, batched):          # warm both compile caches
             fn()
@@ -434,7 +434,7 @@ def main() -> None:
                     help="training budget (default: 20; 30 for --sweep)")
     ap.add_argument("--batches", default="32,64,128")
     ap.add_argument("--kparty", action="store_true",
-                    help="run the K-party train_many vs sequential sweep")
+                    help="run the K-party train_lanes vs sequential sweep")
     ap.add_argument("--ks", default="2,4,8")
     ap.add_argument("--sweep", action="store_true",
                     help="benchmark the replica-lane sweep engine "

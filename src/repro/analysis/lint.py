@@ -15,7 +15,7 @@ through resolvable references before any rule runs:
   ``jax.custom_vjp``), defs passed as arguments to a tracing call
   (``lax.scan``/``cond``/``while_loop``/``switch``, ``pl.pallas_call``,
   ``jax.jit(self._impl)``), defs passed to the training engine's
-  loss-consuming entry points (``training.train*`` / ``get_*engine``),
+  loss-consuming entry points (``training.train*`` / ``get_lanes*engine``),
   and — by repo convention — defs named ``loss`` / ``*_loss`` (losses are
   always consumed by a jitted engine);
 * **propagation** — a def lexically nested in a traced def is traced; any
@@ -62,9 +62,8 @@ TRACING_CALLS = {
 # the engine contract of repro.core.training
 ENGINE_CALLS = {
     f"repro.core.training.{name}" for name in (
-        "train", "train_epochwise", "train_lanes", "train_lanes_epochwise",
-        "train_many", "get_engine", "get_fit_engine", "get_lanes_engine",
-        "get_lanes_fit_engine", "get_many_engine")
+        "train", "train_lanes", "train_lanes_epochwise", "get_lanes_engine",
+        "get_lanes_fit_engine")
 }
 
 # defs with these names are traced by convention: losses are consumed by
@@ -81,9 +80,7 @@ STATIC_ANNOTATIONS = {"int", "str", "bool", "float"}
 # factory functions whose RETURN VALUE is a jitted callable donating these
 # positional argument indices (R004 tracks variables assigned from them)
 DONATING_FACTORIES = {
-    "repro.core.training.get_engine": (0, 1),
-    "repro.core.training.get_lanes_engine": (0, 1),
-    "repro.core.training.get_many_engine": (0, 1),
+    "repro.core.training.get_lanes_engine": (0,),
 }
 
 

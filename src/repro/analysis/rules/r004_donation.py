@@ -1,15 +1,16 @@
 """R004 — donated buffer reused after a ``donate_argnums`` call.
 
-The engine donates ``(params, opt_state)`` into each jitted epoch step so
-XLA can update in place.  Reading the donated Python name afterwards hits
-a deleted buffer (``RuntimeError: Array has been deleted``) — or, in a
-loop, passes a dead buffer back into the next iteration.  The fix is the
-engine's own idiom: rebind the name from the call's results
-(``params, opt_state, ... = engine(params, opt_state, ...)``).
+The lane engine's epoch program donates its carry (params, Adam state
+and early-stop state) into each call so XLA can update in place.  Reading
+the donated Python name afterwards hits a deleted buffer
+(``RuntimeError: Array has been deleted``) — or, in a loop, passes a dead
+buffer back into the next iteration.  The fix is the engine's own idiom:
+rebind the name from the call's results
+(``carry, ... = run_epoch(carry, ...)``).
 
 Tracked donors: defs with ``donate_argnums`` (decorator or ``jax.jit``
 call site) and variables holding the result of the known donating engine
-factories (``get_engine`` / ``get_lanes_engine`` / ``get_many_engine``).
+factory (``get_lanes_engine``, the epoch program's).
 Loop bodies are scanned twice so iteration-carried reuse surfaces.
 """
 from __future__ import annotations
@@ -60,7 +61,7 @@ class _Scanner(FnScanner):
             for node in walk_no_defs(expr):
                 if isinstance(node, ast.Call):
                     self._maybe_donate(node)
-        # 3) engine-factory bindings (`eng = get_engine(...)`)
+        # 3) engine-factory bindings (`eng = get_lanes_engine(...)`)
         if isinstance(s, ast.Assign) and isinstance(s.value, ast.Call):
             d = self.mod.dotted(s.value.func)
             positions = DONATING_FACTORIES.get(d) or DONATING_FACTORIES.get(

@@ -85,7 +85,7 @@ def recon_loss(params: dict, batch: dict) -> jax.Array:
 
 def masked_recon_loss(params: dict, batch: dict) -> jax.Array:
     """``recon_loss`` over the padded-stack batches of
-    ``training.train_many``: ``mask`` (D,) selects the party's real feature
+    ``training.train_lanes``: ``mask`` (D,) selects the party's real feature
     columns, ``row_w`` (B,) its real rows.  With no padding this equals
     ``recon_loss`` exactly (mean over real entries)."""
     x, fm, rw = batch["x"], batch["mask"], batch["row_w"]

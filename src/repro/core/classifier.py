@@ -274,11 +274,15 @@ def kfold_cv_many(xs, ys, n_classes: int, *, k: int = 10, seeds,
     + one host sync for all S x k probes).  Returns one metrics dict per
     seed, each matching ``kfold_cv(xs[i], ys[i], ..., seed=seeds[i])``
     within lane-engine tolerance."""
-    seeds = list(seeds)
     ys = [np.asarray(y) for y in ys]
-    x_s = jnp.stack([jnp.asarray(x) for x in xs])      # (S, n, d)
-    y_s = jnp.stack([jnp.asarray(y) for y in ys])
-    per_seed = [_fold_arrays(x_s.shape[1], k, s) for s in seeds]
+    # one seed rides with a copy of itself: XLA compiles a stack of one
+    # unlike a stack of many, and rounds differently, so a seed's
+    # predictions would depend on how many seeds share the call
+    reps = 2 if len(ys) == 1 else 1
+    x_s = jnp.stack([jnp.asarray(x) for x in xs] * reps)      # (S, n, d)
+    y_s = jnp.stack([jnp.asarray(y) for y in ys] * reps)
+    per_seed = [_fold_arrays(x_s.shape[1], k, s)
+                for s in list(seeds) * reps]
     preds = np.asarray(_fit_predict_folds_many(
         x_s, y_s,
         jnp.asarray(np.stack([p[0] for p in per_seed])),

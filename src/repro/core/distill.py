@@ -41,7 +41,7 @@ def make_loss(lam: float = 0.01, kind: str = "mse", use_kernel: bool = False):
         return distill_loss(params, batch, lam=lam, kind=kind,
                             use_kernel=use_kernel)
     # semantic identity: every closure with the same hyperparameters shares
-    # one compiled training engine (training.get_engine) instead of
+    # one compiled lane engine (training.get_lanes_fit_engine) instead of
     # re-tracing per make_loss() call
     loss.cache_key = ("repro.core.distill.make_loss", float(lam), str(kind),
                       bool(use_kernel))

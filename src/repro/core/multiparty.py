@@ -15,22 +15,22 @@ already-shrunk running intersection, which would both leak information
 about the other links and under-count bytes — so total PSI traffic is
 monotone in K.
 
-All K g1 stages (active + passives) train together through the replica-
-lane engine (``training.train_lanes``, one lane per party): per-party
-params and datasets are zero-padded to common shapes, stacked along a
-leading lane axis, and every epoch runs as ONE vmapped ``lax.scan`` inside
-a single jitted call — one upload, one compile, one host sync per epoch
-for all parties.  Parties that early-stop keep stepping on frozen params
-behind a per-lane mask (the masked-select twin of ``distill.make_loss``),
-so the batch shape stays static; see the ``core.training`` module
-docstring for the layout.  Stage handoffs stay device-resident (latents
-feed g2/g3 as jax arrays; channel accounting reads only shapes), matching
-``core.pipeline``.
+All K g1 stages (active + passives) train together through the lane
+engine (``training.train_lanes``, one lane per party): the parties' params
+and datasets are stacked by shape group on a leading lane axis, and the
+whole fit runs as one vmapped scan with one host sync.  Parties that
+early-stop keep their params behind a per-lane ``live`` flag, so the batch
+shape stays static; see the ``core.training`` module docstring.  Stage
+handoffs stay device-resident (latents feed g2/g3 as jax arrays; channel
+accounting reads only shapes), matching ``core.pipeline``.
 
-Hyperparameter defaults come from ``configs.apcvfl_paper.TABULAR``;
-``run_apcvfl_k`` returns the unified ``experiments.results.RunResult``
-whose ``channels`` tuple holds one measured ``comm.Channel`` per passive
-link (``rounds`` is the paper's per-link claim: ONE data exchange)."""
+One implementation runs the protocol: ``run_apcvfl_k_replicated`` takes S
+seed replicates through every stage as lanes, and ``run_apcvfl_k`` is its
+one-seed case.  Hyperparameter defaults come from
+``configs.apcvfl_paper.TABULAR``; both return the unified
+``experiments.results.RunResult``, whose ``channels`` tuple holds one
+measured ``comm.Channel`` per passive link (``rounds`` is the paper's
+per-link claim: ONE data exchange)."""
 from __future__ import annotations
 
 from dataclasses import dataclass
@@ -121,97 +121,12 @@ def run_apcvfl_k(sc: VFLScenarioK, *, lam: float = HP.lam,
                  patience: int = HP.patience, lr: float = HP.lr,
                  use_kernel: bool = False,
                  ablation: bool = False, exchange=None) -> RunResult:
-    """K-party protocol; same feature set as the 2-party ``run_apcvfl``
-    (``ablation=True`` trains g3 without the distillation term;
-    ``exchange`` hardens every passive link's one-shot latent send — each
-    link derives its own transform randomness via its link index)."""
-    key = jax.random.PRNGKey(seed)
-    keys = jax.random.split(key, len(sc.passives) + 3)
-    epochs = {}
-    train_kw = dict(batch_size=batch_size, max_epochs=max_epochs,
-                    patience=patience, lr=lr)
-
-    common, channels = align_k(sc.active.ids, [p.ids for p in sc.passives])
-    idx_a = _index_of(sc.active.ids, common)
-    idx_ps = [_index_of(p.ids, common) for p in sc.passives]
-    xa = sc.active.x
-
-    if not ablation:
-        # --- step 1 at every party: ONE vmapped run, one lane per g1 -------
-        specs = [training.LaneSpec(
-            ae.init_autoencoder(keys[0],
-                                ae.table3_encoder("g1_active", xa.shape[1])),
-            {"x": xa}, seed)]
-        for i, p in enumerate(sc.passives):
-            specs.append(training.LaneSpec(
-                ae.init_autoencoder(keys[i + 1],
-                                    ae.table3_encoder("g1_passive",
-                                                      p.x.shape[1])),
-                {"x": p.x}, seed + i + 1))
-        results = training.train_lanes(specs, ae.masked_recon_loss,
-                                       **train_kw)
-        ra, r_ps = results[0], results[1:]
-        epochs["g1_active"] = ra.epochs_run
-        za = ae.encode(ra.params, jnp.asarray(xa[idx_a]))
-
-        blocks = [za]
-        for i, (p, idx_p, ch, rp) in enumerate(zip(sc.passives, idx_ps,
-                                                   channels, r_ps)):
-            epochs[f"g1_passive{i}"] = rp.epochs_run
-            zp = ae.encode(rp.params, jnp.asarray(p.x[idx_p]))
-            zp = comm.exchange_array(                          # THE exchange
-                ch, f"step1/Z_passive{i}_aligned", zp,
-                transform=exchange, seed=seed, link=i)
-            blocks.append(zp)
-
-        # --- step 2 at the active party -------------------------------------
-        zj = jnp.concatenate(blocks, axis=1).astype(jnp.float32)
-        # singleton lane: bit-identical twin of the replicated g2 stage
-        (r2,) = training.train_lanes(
-            [training.LaneSpec(
-                ae.init_autoencoder(keys[-2],
-                                    ae.table3_encoder("g2", zj.shape[1])),
-                {"x": zj}, seed + 100)],
-            ae.masked_recon_loss, **train_kw)
-        epochs["g2"] = r2.epochs_run
-        zt_al = ae.encode(r2.params, zj)
-        m2 = zt_al.shape[1]
-    else:
-        m2 = ae.table3_encoder("g2", 1)[-1]
-        zt_al = None
-
-    # --- steps 3-4 at the active party --------------------------------------
-    n_a = len(xa)
-    z_teacher = jnp.zeros((n_a, m2), jnp.float32)
-    mask = jnp.zeros((n_a,), jnp.float32)
-    if not ablation:
-        z_teacher = z_teacher.at[idx_a].set(zt_al)
-        mask = mask.at[idx_a].set(1.0)
-    r3 = training.train(
-        ae.init_autoencoder(keys[-1], ae.table3_encoder("g3", xa.shape[1])),
-        {"x": xa, "z_teacher": z_teacher, "aligned": mask},
-        distill.make_loss(lam=lam, kind=kind, use_kernel=use_kernel),
-        seed=seed + 200, **train_kw)
-    epochs["g3"] = r3.epochs_run
-
-    z_all = ae.encode(r3.params, jnp.asarray(xa))
-    metrics = clf.kfold_cv(z_all, sc.active.y, sc.n_classes, seed=seed)
-    data_rounds = 0 if ablation else comm.APCVFL_ROUNDS
-    params = {"g3": r3.params}
-    artifacts = None
-    if not ablation:
-        # serving export capture (serve.vfl.export_bundle): the active
-        # party's own encoders + the concat of the K-1 received latent
-        # blocks for the aligned rows, keyed by their ids
-        params["g1_active"] = ra.params
-        params["g2"] = r2.params
-        artifacts = {"aligned_ids": np.asarray(common),
-                     "z_passive_aligned": jnp.concatenate(blocks[1:],
-                                                          axis=1)}
-    return RunResult(method="apcvfl", metrics=metrics, rounds=data_rounds,
-                     epochs=epochs, comm=comm.summarize(channels), seed=seed,
-                     z_dim=m2, params=params, channels=tuple(channels),
-                     artifacts=artifacts)
+    """K-party protocol for one seed: ``run_apcvfl_k_replicated`` with one
+    seed lane (the same keyword surface as the 2-party ``run_apcvfl``)."""
+    return run_apcvfl_k_replicated(
+        sc, seeds=[seed], lam=lam, kind=kind, batch_size=batch_size,
+        max_epochs=max_epochs, patience=patience, lr=lr,
+        use_kernel=use_kernel, ablation=ablation, exchange=exchange)[0]
 
 
 def _index_of(ids: np.ndarray, subset: np.ndarray) -> np.ndarray:
@@ -237,9 +152,10 @@ def run_apcvfl_k_replicated(scenarios, *, seeds, lam: float = HP.lam,
     lanes, then S g3 lanes — the K-party twin of
     ``pipeline.run_apcvfl_replicated`` (same contract: one scenario
     shared by every seed or one equal-shape scenario per seed; one
-    ``RunResult`` per seed matching ``run_apcvfl_k(scenarios[i],
-    seed=seeds[i], ...)`` within lane tolerance).  ``mesh`` shards every
-    stage's lane axis across devices (see ``training.train_lanes``)."""
+    ``RunResult`` per seed; ``ablation`` and ``exchange`` as there, each
+    passive link deriving its own transform randomness from its link
+    index).  ``mesh`` shards every stage's lane axis across devices (see
+    ``training.train_lanes``)."""
     seeds = [int(s) for s in seeds]
     S = len(seeds)
     scs = ([scenarios] * S if isinstance(scenarios, VFLScenarioK)

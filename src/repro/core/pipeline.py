@@ -10,30 +10,21 @@ Step 3  knowledge distillation          (active, student AE g3 on the FULL
         active dataset, Eq. 5 masked loss)
 Step 4  classifier on Z = g3(X_active), labels from the active party.
 
-All stages train on the device-resident scan engine (``core.training``):
-each stage uploads its arrays once and runs whole epochs as a single jitted
-scan, and every ``distill.make_loss`` closure with equal hyperparameters
-reuses the g3 engine via its semantic cache key.  The two step-1 (g1)
-autoencoders train TOGETHER through ``training.train_lanes`` — params and
-data zero-padded to common shapes, stacked on a leading lane axis, every
-epoch one vmapped scan — the same lane engine ``core.multiparty`` uses
-for K parties (this is the 2-lane special case).
+One implementation runs the protocol: ``run_apcvfl_replicated`` takes S
+seed replicates of one grid cell through every stage together, each stage
+S (or 2S, for the two g1s) lanes of one ``training.train_lanes`` call —
+one compile and one host sync per stage, not S of each.  ``run_apcvfl``
+is its one-seed case, and the aligned-only pair is built the same way.
+The ``*_replicated`` entry points take an optional ``mesh``
+(``repro.launch.mesh.make_lane_mesh``) that shards every stage's lane
+axis across devices — same computation, device-parallel lanes.
 
 Stage handoffs are device-resident: encoder outputs feed the next stage as
 jax arrays (the lane engine gathers its train/val splits on device) and
 the channel accounting reads only shapes/dtypes, so the handoffs
 themselves add NO host round-trips — what remains is the engine's single
-early-stop sync per FIT (the fused scan-of-scans engine keeps the whole
-epoch loop on device) and the final metrics evaluation
-(``clf.kfold_cv``, one sync for all folds).
-
-``run_apcvfl_replicated`` runs S seed replicates of one grid cell through
-every stage together: each stage becomes S (or 2S, for the two g1s) lanes
-of one ``training.train_lanes`` call, so a whole multi-seed sweep cell
-costs one compile and one host sync per stage instead of S of each.  Both
-``*_replicated`` entry points take an optional ``mesh``
-(``repro.launch.mesh.make_lane_mesh``) that shards every stage's lane
-axis across devices — same computation, device-parallel lanes.
+early-stop sync per stage and the k-fold probe's (``clf.kfold_cv_many``,
+one sync for every seed's folds).
 
 Hyperparameter defaults come from ``configs.apcvfl_paper.TABULAR`` (the
 paper's Appendix-B settings); every entry point returns the unified
@@ -63,107 +54,12 @@ def run_apcvfl(sc: VFLScenario, *, lam: float = HP.lam, kind: str = HP.kind,
                max_epochs: int = HP.max_epochs, patience: int = HP.patience,
                lr: float = HP.lr, use_kernel: bool = False,
                ablation: bool = False, exchange=None) -> RunResult:
-    """Full protocol. ``ablation=True`` trains g3 WITHOUT the distillation
-    term (paper's 'Ablation' curves — isolates the nonlinear-encoder gain).
-
-    ``exchange`` hardens the single latent exchange: an
-    ``ExchangeTransform`` (``repro.robustness.defense`` — DP noise,
-    quantization) applied at the sender.  Everything downstream of the
-    exchange (g2, g3, the serving artifacts) consumes the RECEIVED
-    latents, and the channel accounts the transformed wire bytes.
-    ``None`` (default) is the paper's plain fp32 exchange, bit-identical
-    to the pre-hook behavior.
-    """
-    key = jax.random.PRNGKey(seed)
-    k1, k2, k3, k4 = jax.random.split(key, 4)
-    channel = comm.Channel()
-    epochs = {}
-    train_kw = dict(batch_size=batch_size, max_epochs=max_epochs,
-                    patience=patience, lr=lr)
-
-    # --- PSI on IDs (assumed precondition in the paper; bytes logged) ------
-    aligned_ids, idx_a, idx_p = psi(sc.active.ids, sc.passive.ids,
-                                    channel=channel)
-
-    xa, xp = sc.active.x, sc.passive.x
-
-    # --- Step 1: local representation learning -----------------------------
-    if not ablation:
-        wa = ae.table3_encoder("g1_active", xa.shape[1])
-        wp = ae.table3_encoder("g1_passive", xp.shape[1])
-        ae_a = ae.init_autoencoder(k1, wa)
-        ae_p = ae.init_autoencoder(k2, wp)
-        ra, rp = training.train_lanes(
-            [training.LaneSpec(ae_a, {"x": xa}, seed),
-             training.LaneSpec(ae_p, {"x": xp}, seed + 1)],
-            ae.make_masked_recon_loss(use_kernel), **train_kw)
-        epochs["g1_active"], epochs["g1_passive"] = ra.epochs_run, rp.epochs_run
-
-        # device-resident handoff: latents stay jax arrays end to end
-        za_al = ae.encode(ra.params, jnp.asarray(xa[idx_a]))
-        zp_al = ae.encode(rp.params, jnp.asarray(xp[idx_p]))
-
-        # THE single information exchange: passive -> active, aligned
-        # latents (byte accounting reads only shape/dtype — no host
-        # sync).  With a transform, zp_al becomes what the active party
-        # RECEIVED — the only form g2/g3/serving may ever see.
-        zp_al = comm.exchange_array(channel, "step1/Z_passive_aligned",
-                                    zp_al, transform=exchange, seed=seed)
-
-        # --- Step 2: aligned (joint) representation learning ---------------
-        zj = jnp.concatenate([za_al, zp_al], axis=1).astype(jnp.float32)
-        w2 = ae.table3_encoder("g2", zj.shape[1])
-        ae_2 = ae.init_autoencoder(k3, w2)
-        # singleton lane (not training.train): the SAME engine + loss the
-        # replicated path runs, so rep-vs-seq g2 params are bit-identical
-        # (the probe is chaotic enough to amplify a 1e-8 loss-reduction
-        # reordering into whole flipped CV predictions)
-        (r2,) = training.train_lanes(
-            [training.LaneSpec(ae_2, {"x": zj}, seed + 2)],
-            ae.make_masked_recon_loss(use_kernel), **train_kw)
-        epochs["g2"] = r2.epochs_run
-        z_teacher_al = ae.encode(r2.params, zj)
-        m2 = z_teacher_al.shape[1]
-    else:
-        m2 = ae.table3_encoder("g2", 1)[-1]
-        z_teacher_al = None
-
-    # --- Step 3: knowledge distillation into g3 -----------------------------
-    n_a = len(xa)
-    z_teacher = jnp.zeros((n_a, m2), jnp.float32)
-    mask = jnp.zeros((n_a,), jnp.float32)
-    if not ablation:
-        z_teacher = z_teacher.at[idx_a].set(z_teacher_al)
-        mask = mask.at[idx_a].set(1.0)
-    w3 = ae.table3_encoder("g3", xa.shape[1])
-    assert w3[-1] == m2, "M3 == M2: dimensional consistency (Sec. 4.3)"
-    ae_3 = ae.init_autoencoder(k4, w3)
-    loss3 = distill.make_loss(lam=lam, kind=kind, use_kernel=use_kernel)
-    r3 = training.train(ae_3, {"x": xa, "z_teacher": z_teacher,
-                               "aligned": mask}, loss3, seed=seed + 3,
-                        **train_kw)
-    epochs["g3"] = r3.epochs_run
-
-    # --- Step 4: classifier on the enhanced dataset -------------------------
-    # the protocol's single host sync: kfold_cv pulls predictions once
-    z_all = ae.encode(r3.params, jnp.asarray(xa))
-    metrics = clf.kfold_cv(z_all, sc.active.y, sc.n_classes, seed=seed)
-
-    data_rounds = 0 if ablation else comm.APCVFL_ROUNDS
-    params = {"g3": r3.params}
-    artifacts = None
-    if not ablation:
-        # everything the active party holds after training, captured for
-        # serving export (serve.vfl.export_bundle): its own encoders plus
-        # the passive latents it RECEIVED — never the passive party's model
-        params["g1_active"] = ra.params
-        params["g2"] = r2.params
-        artifacts = {"aligned_ids": np.asarray(aligned_ids),
-                     "z_passive_aligned": zp_al}
-    return RunResult(method="apcvfl", metrics=metrics, rounds=data_rounds,
-                     epochs=epochs, comm=channel.summary(), seed=seed,
-                     z_dim=m2, params=params, channels=(channel,),
-                     artifacts=artifacts)
+    """Full protocol for one seed: ``run_apcvfl_replicated`` with one seed
+    lane (its docstring gives ``ablation`` and ``exchange``)."""
+    return run_apcvfl_replicated(
+        sc, seeds=[seed], lam=lam, kind=kind, batch_size=batch_size,
+        max_epochs=max_epochs, patience=patience, lr=lr,
+        use_kernel=use_kernel, ablation=ablation, exchange=exchange)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -193,27 +89,34 @@ def run_apcvfl_replicated(scenarios, *, seeds, lam: float = HP.lam,
     """Full protocol for S seed replicates of one grid cell, every stage
     one ``training.train_lanes`` dispatch: the two g1s of all seeds run as
     2S lanes, g2 as S lanes, g3 as S lanes — one compile and one host sync
-    per epoch for the whole replica set instead of S of each.
+    per stage for the whole replica set instead of S of each.
 
     ``scenarios`` is a single ``VFLScenario`` shared by every seed, or a
     sequence of per-seed scenarios of EQUAL shapes (a sweep group: same
     dataset / n_aligned / feature split, different partition seeds).
-    Returns one ``RunResult`` per seed, each matching what
-    ``run_apcvfl(scenarios[i], seed=seeds[i], ...)`` produces to float
-    tolerance (per-lane trajectories are lane-local; tests/test_replicas.py
-    pins the parity).  ``use_kernel=True`` runs the g3 lanes through the
-    fused Eq. 5 Pallas kernel (``distill.make_lanes_loss(use_kernel=True)``
-    — trainable since the kernel grew its closed-form custom VJP).
-    ``mesh`` shards every stage's lane axis across devices (see
-    ``training.train_lanes``).
+    Returns one ``RunResult`` per seed; a lane's trajectories are its own,
+    so each matches a one-seed run to float tolerance
+    (tests/test_replicas.py pins the parity).
+
+    ``ablation=True`` trains g3 WITHOUT the distillation term (the
+    paper's 'Ablation' curves — isolates the nonlinear-encoder gain).
+    ``exchange`` hardens the single latent exchange: an
+    ``ExchangeTransform`` (``repro.robustness.defense`` — DP noise,
+    quantization) applied at the sender.  Everything downstream of the
+    exchange (g2, g3, the serving artifacts) consumes the RECEIVED
+    latents, and the channel accounts the transformed wire bytes; ``None``
+    is the paper's plain fp32 exchange.  ``use_kernel=True`` runs the g3
+    lanes through the fused Eq. 5 Pallas kernel
+    (``distill.make_lanes_loss(use_kernel=True)``).  ``mesh`` shards every
+    stage's lane axis across devices (see ``training.train_lanes``).
 
     ``exchange`` is one ``ExchangeTransform`` shared by every replica or
     a per-replica sequence (entries may be ``None``): a whole defense
     grid — e.g. one sigma per lane via ``robustness.defense.dp_frontier``
     — runs its g1/g2/g3 stages as lanes of the same vmapped scans, with
     only the cheap eager exchange differing per lane.  Per-lane noise
-    keys derive from each lane's SEED (not its lane index), so a lane
-    matches ``run_apcvfl(sc, seed=s, exchange=t)`` exactly."""
+    keys derive from each lane's SEED (not its lane index), so a lane's
+    noise is ``run_apcvfl(sc, seed=s, exchange=t)``'s."""
     scs, seeds = _normalize_replicas("run_apcvfl_replicated", scenarios,
                                      seeds)
     S = len(seeds)
@@ -349,50 +252,11 @@ def run_apcvfl_aligned_only(sc: VFLScenario, *, seed: int = 0,
                             max_epochs: int = HP.max_epochs,
                             patience: int = HP.patience, lr: float = HP.lr,
                             test_size: int = HP.test_size) -> RunResult:
-    """Classical fully-aligned setting: train the classifier directly on the
-    joint latents g2(concat(Z_a, Z_p)); distillation is skipped (no
-    unaligned rows exist to distill into)."""
-    key = jax.random.PRNGKey(seed)
-    k1, k2, k3 = jax.random.split(key, 3)
-    channel = comm.Channel()
-    train_kw = dict(batch_size=batch_size, max_epochs=max_epochs,
-                    patience=patience, lr=lr)
-    _, idx_a, idx_p = psi(sc.active.ids, sc.passive.ids, channel=channel)
-    xa, xp = sc.active.x[idx_a], sc.passive.x[idx_p]
-    y = sc.active.y[idx_a]
-
-    ae_a = ae.init_autoencoder(k1, ae.table3_encoder("g1_active", xa.shape[1]))
-    ae_p = ae.init_autoencoder(k2, ae.table3_encoder("g1_passive", xp.shape[1]))
-    ra, rp = training.train_lanes(
-        [training.LaneSpec(ae_a, {"x": xa}, seed),
-         training.LaneSpec(ae_p, {"x": xp}, seed + 1)],
-        ae.masked_recon_loss, **train_kw)
-    za = ae.encode(ra.params, jnp.asarray(xa))
-    zp = ae.encode(rp.params, jnp.asarray(xp))
-    channel.send_array("step1/Z_passive_aligned", zp, direction="uplink")
-
-    zj = jnp.concatenate([za, zp], 1).astype(jnp.float32)
-    ae_2 = ae.init_autoencoder(k3, ae.table3_encoder("g2", zj.shape[1]))
-    # singleton lane: bit-identical twin of the replicated g2 stage
-    (r2,) = training.train_lanes(
-        [training.LaneSpec(ae_2, {"x": zj}, seed + 2)],
-        ae.masked_recon_loss, **train_kw)
-    z = np.asarray(ae.encode(r2.params, zj))
-
-    # train/test split as in the SplitNN comparison (test_size held out)
-    rng = np.random.RandomState(seed)
-    perm = rng.permutation(len(z))
-    te, tr = perm[:test_size], perm[test_size:]
-    params = clf.fit_logreg(jnp.asarray(z[tr]), jnp.asarray(y[tr]),
-                            sc.n_classes)
-    pred = clf.predict(params, z[te])
-    metrics = clf.f1_scores(y[te], pred, sc.n_classes)
-    return RunResult(method="apcvfl_aligned_only", metrics=metrics, rounds=1,
-                     epochs={"g1_active": ra.epochs_run,
-                             "g1_passive": rp.epochs_run,
-                             "g2": r2.epochs_run},
-                     comm=channel.summary(), seed=seed, z_dim=z.shape[1],
-                     params={"g2": r2.params}, channels=(channel,))
+    """The aligned-only adaptation for one seed:
+    ``run_apcvfl_aligned_only_replicated`` with one seed lane."""
+    return run_apcvfl_aligned_only_replicated(
+        sc, seeds=[seed], batch_size=batch_size, max_epochs=max_epochs,
+        patience=patience, lr=lr, test_size=test_size)[0]
 
 
 def run_apcvfl_aligned_only_replicated(scenarios, *, seeds,
@@ -402,14 +266,14 @@ def run_apcvfl_aligned_only_replicated(scenarios, *, seeds,
                                        lr: float = HP.lr,
                                        test_size: int = HP.test_size,
                                        mesh=None) -> list:
-    """S seed replicates of the aligned-only adaptation, every stage one
-    ``train_lanes`` dispatch (2S g1 lanes, S g2 lanes).  Both of its
-    stages are dispatch-bound at tabular shapes, so this is the replica
-    grid where lane batching pays most on CPU (see
-    ``benchmarks/trainbench.py --sweep``).  Same contract as
-    ``run_apcvfl_replicated``: one scenario shared or one per seed, one
-    ``RunResult`` per seed matching the sequential path within lane
-    tolerance.  ``mesh`` shards every stage's lane axis across devices."""
+    """Classical fully-aligned setting: train the classifier directly on
+    the joint latents g2(concat(Z_a, Z_p)); distillation is skipped (no
+    unaligned rows exist to distill into).
+
+    S seed replicates, every stage one ``train_lanes`` dispatch (2S g1
+    lanes, S g2 lanes).  Same contract as ``run_apcvfl_replicated``: one
+    scenario shared or one per seed, one ``RunResult`` per seed.  ``mesh``
+    shards every stage's lane axis across devices."""
     scs, seeds = _normalize_replicas("run_apcvfl_aligned_only_replicated",
                                      scenarios, seeds)
     S = len(seeds)

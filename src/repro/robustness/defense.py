@@ -202,20 +202,13 @@ def run_apcvfl_dp(sc, *, sigma: float = 0.0, mechanism: str = "gaussian",
                   use_kernel: bool = False,
                   ablation: bool = False) -> RunResult:
     """The full protocol with a hardened exchange (``@register_method
-    ("apcvfl_dp")``): same training surface as ``apcvfl`` plus the
-    defense knobs.  Routes K-party scenarios to ``run_apcvfl_k`` (every
-    passive link gets the same transform, link-separated noise).  With
-    every defense off this IS ``run_apcvfl`` bit-for-bit."""
-    t = make_transform(sigma=sigma, mechanism=mechanism, clip=clip,
-                       quantize=quantize)
-    kw = dict(lam=lam, kind=kind, batch_size=batch_size,
-              max_epochs=max_epochs, patience=patience, lr=lr,
-              use_kernel=use_kernel, ablation=ablation, exchange=t)
-    if isinstance(sc, VFLScenarioK):
-        res = multiparty.run_apcvfl_k(sc, seed=seed, **kw)
-    else:
-        res = pipeline.run_apcvfl(sc, seed=seed, **kw)
-    return _tag_dp(res, sigma=sigma)
+    ("apcvfl_dp")``) for one seed: ``run_apcvfl_dp_replicated`` with one
+    seed lane.  With every defense off this IS ``run_apcvfl``."""
+    return run_apcvfl_dp_replicated(
+        [sc], seeds=[seed], sigma=sigma, mechanism=mechanism, clip=clip,
+        quantize=quantize, lam=lam, kind=kind, batch_size=batch_size,
+        max_epochs=max_epochs, patience=patience, lr=lr,
+        use_kernel=use_kernel, ablation=ablation)[0]
 
 
 def run_apcvfl_dp_replicated(scenarios, *, seeds, sigma: float = 0.0,
@@ -231,7 +224,9 @@ def run_apcvfl_dp_replicated(scenarios, *, seeds, sigma: float = 0.0,
                              mesh=None) -> List[RunResult]:
     """Seed replicas of one defended grid cell through the replica-lane
     engine: the transform is shared across seeds (per-seed noise keys),
-    every protocol stage S lanes of one vmapped scan."""
+    every protocol stage S lanes of one vmapped scan.  K-party scenarios
+    go to ``run_apcvfl_k_replicated`` (every passive link gets the same
+    transform, link-separated noise)."""
     t = make_transform(sigma=sigma, mechanism=mechanism, clip=clip,
                        quantize=quantize)
     kw = dict(seeds=seeds, lam=lam, kind=kind, batch_size=batch_size,

@@ -1,4 +1,4 @@
-"""The lane engines' epoch batch order: each epoch's batches are sliced
+"""The lane engine's epoch batch order: each epoch's batches are sliced
 straight from the lane's permutation of its train rows, with no
 partition of that permutation in the compiled program, and ``_prep_lanes``
 refuses a stack whose lanes do not share their train rows (the invariant
@@ -30,22 +30,23 @@ def _specs(rows, widths=None, seed=0):
         seed=seed + i) for i, (n, d) in enumerate(zip(rows, widths))]
 
 
-def _compiled_engine_text(kind):
-    params, opt, keys, tr, val, nb, bs = training._prep_lanes(
+def _lowered_engine(kind):
+    params, opt, keys, tr, val, n_batches, bs = training._prep_lanes(
         _specs([N_TR + 100] * L), batch_size=64, val_frac=0.1, lr=1e-3)
     assert tr["x"].shape[:2] == (L, N_TR)
-    nb_dev, live = jnp.asarray(nb, jnp.int32), jnp.ones((L,), bool)
+    live = jnp.ones((L,), bool)
+    kw = dict(n_batches=n_batches, batch_size=bs, patience=2)
     if kind == "fit":
         engine = training.get_lanes_fit_engine(ae.masked_recon_loss)
-        lowered = engine.lower(params, opt, keys, tr, val, nb_dev, live,
-                               n_batches=int(nb.max()), batch_size=bs,
-                               max_epochs=3, patience=2, uniform=True)
-    else:
-        engine = training.get_lanes_engine(ae.masked_recon_loss)
-        ekeys = training._FOLD_KEYS(keys, 0)
-        lowered = engine.lower(params, opt, ekeys, tr, val, nb_dev, live,
-                               n_batches=int(nb.max()), batch_size=bs)
-    return lowered.compile().as_text()
+        return engine.lower(params, opt, keys, tr, val, live, max_epochs=3,
+                            **kw)
+    engine = training.get_lanes_engine(ae.masked_recon_loss)
+    carry = training._carry0(params, opt, params, live)
+    return engine.lower(carry, 0, keys, tr, val, **kw)
+
+
+def _compiled_engine_text(kind):
+    return _lowered_engine(kind).compile().as_text()
 
 
 @pytest.mark.parametrize("kind", ["fit", "epochwise"])
@@ -64,6 +65,17 @@ def test_engine_epoch_has_no_permutation_partition(kind):
     assert not perm_gathers, perm_gathers
 
 
+def test_program_names_tell_the_scan_from_the_epoch():
+    """The device trace finds the whole-fit scan by the substring
+    ``run_fit_k`` in its module name; the epoch program must not match."""
+    names = {kind: re.search(r"module @(\S+)",
+                             _lowered_engine(kind).as_text()).group(1)
+             for kind in ("fit", "epochwise")}
+    assert "run_fit_k" in names["fit"], names
+    assert "run_fit_k" not in names["epochwise"], names
+    assert "run_epoch_k" in names["epochwise"], names
+
+
 def test_prep_lanes_refuses_unequal_train_rows():
     with pytest.raises(ValueError, match="unequal train rows"):
         training._prep_lanes(_specs([120, 150]), batch_size=16,
@@ -73,10 +85,10 @@ def test_prep_lanes_refuses_unequal_train_rows():
 def test_prep_lanes_accepts_equal_train_rows_with_unequal_val_rows():
     # 19 and 20 rows both hold out 1-2 rows and train on 18: only the
     # validation stack is row-padded, and its padded rows weigh zero
-    params, opt, keys, tr, val, nb, bs = training._prep_lanes(
+    params, opt, keys, tr, val, n_batches, bs = training._prep_lanes(
         _specs([19, 20]), batch_size=8, val_frac=0.1, lr=1e-3)
     assert tr["x"].shape[:2] == (2, 18)
-    assert list(nb) == [2, 2] and bs == 8
+    assert n_batches == 2 and bs == 8
     np.testing.assert_array_equal(np.asarray(val["row_w"]),
                                   [[1.0, 0.0], [1.0, 1.0]])
 

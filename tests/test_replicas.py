@@ -200,6 +200,21 @@ def test_run_apcvfl_replicated_single_shared_scenario(replica_cells):
         rep[0].epochs != rep[1].epochs
 
 
+def test_run_apcvfl_is_the_one_seed_replicated_run(replica_cells):
+    """``run_apcvfl`` is ``run_apcvfl_replicated`` with one seed: the same
+    params, epochs, comm and metrics, exactly."""
+    scs, seeds = replica_cells
+    sc, s = scs[1], seeds[1]
+    one = pipeline.run_apcvfl(sc, seed=s, max_epochs=3)
+    (rep,) = pipeline.run_apcvfl_replicated(sc, seeds=[s], max_epochs=3)
+    assert (one.epochs, one.comm, one.metrics) == (rep.epochs, rep.comm,
+                                                   rep.metrics)
+    assert jax.tree.structure(one.params) == jax.tree.structure(rep.params)
+    for a, b in zip(jax.tree.leaves(one.params),
+                    jax.tree.leaves(rep.params)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_aligned_only_replicated_matches_sequential(replica_cells):
     scs, seeds = replica_cells
     kw = dict(max_epochs=3, test_size=30)

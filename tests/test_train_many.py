@@ -1,5 +1,5 @@
-"""Tests for the batched K-party engine (``training.train_many``): parity
-with K independent ``training.train`` calls, uneven feature widths and
+"""Tests for K-party fits as lanes of one engine (``training.train_lanes``):
+parity with K independent ``training.train`` calls, uneven feature widths and
 heterogeneous architectures (padded-stack layout), uneven row counts, and
 per-party early stopping at different epochs."""
 import jax
@@ -43,9 +43,9 @@ def test_parity_uneven_widths_equal_rows():
     specs, solos = [], []
     for i, d in enumerate([5, 9, 7]):
         params, data = _toy(200, d, i)
-        specs.append(training.PartySpec(params, data, seed=i))
+        specs.append(training.LaneSpec(params, data, seed=i))
         solos.append(_solo(params, data, i, **kw))
-    many = training.train_many(specs, ae.masked_recon_loss, **kw)
+    many = training.train_lanes(specs, ae.masked_recon_loss, **kw)
     for s, m in zip(solos, many):
         assert (s.epochs_run, s.steps_run) == (m.epochs_run, m.steps_run)
         np.testing.assert_allclose(s.train_loss, m.train_loss, atol=1e-4)
@@ -62,8 +62,8 @@ def test_parity_heterogeneous_architectures():
     p1, d1 = _toy(160, 6, 0, widths=[6, 8, 16])
     p2, d2 = _toy(160, 11, 1, widths=[11, 16, 32])
     s1, s2 = _solo(p1, d1, 0, **kw), _solo(p2, d2, 1, **kw)
-    m1, m2 = training.train_many(
-        [training.PartySpec(p1, d1, 0), training.PartySpec(p2, d2, 1)],
+    m1, m2 = training.train_lanes(
+        [training.LaneSpec(p1, d1, 0), training.LaneSpec(p2, d2, 1)],
         ae.masked_recon_loss, **kw)
     for s, m in zip((s1, s2), (m1, m2)):
         assert [l.shape for l in jax.tree.leaves(s.params)] == \
@@ -79,8 +79,8 @@ def test_uneven_row_counts_statistical_parity():
     p1, d1 = _toy(150, 6, 0)
     p2, d2 = _toy(260, 6, 1)
     s1, s2 = _solo(p1, d1, 0, **kw), _solo(p2, d2, 1, **kw)
-    m1, m2 = training.train_many(
-        [training.PartySpec(p1, d1, 0), training.PartySpec(p2, d2, 1)],
+    m1, m2 = training.train_lanes(
+        [training.LaneSpec(p1, d1, 0), training.LaneSpec(p2, d2, 1)],
         ae.masked_recon_loss, **kw)
     # party 2 is unpadded (max rows) -> exact parity incl. step counts
     assert (s2.epochs_run, s2.steps_run) == (m2.epochs_run, m2.steps_run)
@@ -103,9 +103,9 @@ def test_per_party_early_stopping_at_different_epochs():
     p_hard, d_hard = _toy(125, 4, 1, widths=[4, 8, 4])
     s_easy = _solo(p_easy, d_easy, 0, **kw)
     s_hard = _solo(p_hard, d_hard, 1, **kw)
-    m_easy, m_hard = training.train_many(
-        [training.PartySpec(p_easy, d_easy, 0),
-         training.PartySpec(p_hard, d_hard, 1)],
+    m_easy, m_hard = training.train_lanes(
+        [training.LaneSpec(p_easy, d_easy, 0),
+         training.LaneSpec(p_hard, d_hard, 1)],
         ae.masked_recon_loss, **kw)
     assert m_easy.epochs_run == s_easy.epochs_run
     assert m_hard.epochs_run == s_hard.epochs_run
@@ -121,7 +121,7 @@ def test_single_party_degenerates_to_train():
     kw = dict(batch_size=64, max_epochs=5, patience=5)
     params, data = _toy(128, 7, 3)
     s = _solo(params, data, 3, **kw)
-    (m,) = training.train_many([training.PartySpec(params, data, 3)],
-                               ae.masked_recon_loss, **kw)
+    (m,) = training.train_lanes([training.LaneSpec(params, data, 3)],
+                                ae.masked_recon_loss, **kw)
     assert (s.epochs_run, s.steps_run) == (m.epochs_run, m.steps_run)
     assert _max_leaf_diff(s.params, m.params) < 1e-4

@@ -150,12 +150,15 @@ def test_make_loss_closures_share_compiled_engine():
     l2 = distill.make_loss(lam=0.07, kind="mae")
     l3 = distill.make_loss(lam=0.08, kind="mae")
     assert l1 is not l2
-    assert training.get_engine(l1) is training.get_engine(l2)
-    assert training.get_engine(l1) is not training.get_engine(l3)
-    assert training.get_fit_engine(l1) is training.get_fit_engine(l2)
-    assert training.get_fit_engine(l1) is not training.get_fit_engine(l3)
-    # epochwise and fused engines live under distinct cache tags
-    assert training.get_fit_engine(l1) is not training.get_engine(l1)
+    assert training.get_lanes_engine(l1) is training.get_lanes_engine(l2)
+    assert training.get_lanes_engine(l1) is not training.get_lanes_engine(l3)
+    assert (training.get_lanes_fit_engine(l1)
+            is training.get_lanes_fit_engine(l2))
+    assert (training.get_lanes_fit_engine(l1)
+            is not training.get_lanes_fit_engine(l3))
+    # the epoch and whole-fit programs are two programs of one cache entry
+    assert (training.get_lanes_fit_engine(l1)
+            is not training.get_lanes_engine(l1))
 
 
 def test_no_recompilation_across_make_loss_instances():
@@ -168,7 +171,7 @@ def test_no_recompilation_across_make_loss_instances():
     params = ae.init_autoencoder(jax.random.PRNGKey(0), [d, 8, m])
     kw = dict(batch_size=32, max_epochs=2, patience=99, seed=0)
 
-    engine = training.get_fit_engine(distill.make_loss(lam=0.11))
+    engine = training.get_lanes_fit_engine(distill.make_loss(lam=0.11))
     if not hasattr(engine, "_cache_size"):   # private jax API; guard it
         pytest.skip("this jax version has no PjitFunction._cache_size")
     training.train(params, data, distill.make_loss(lam=0.11), **kw)
@@ -179,18 +182,24 @@ def test_no_recompilation_across_make_loss_instances():
 
 
 # ---------------------------------------------------------------------------
-# fused scan-of-scans engine vs the epochwise parity oracle
+# the scan program (run_fit_k) vs the host loop over the epoch program
 # ---------------------------------------------------------------------------
 
+def _epochwise(params, data, loss_fn, *, seed, **kw):
+    """One lane through the host loop over ``run_epoch_k``."""
+    (r,) = training.train_lanes_epochwise(
+        [training.LaneSpec(params, data, seed)], loss_fn, **kw)
+    return r
+
+
 def test_fused_matches_epochwise_on_trace_workloads():
-    """The fused whole-fit engine must reproduce the per-epoch-loop engine
-    EXACTLY on the stored-trace workloads: same early-stop epoch count,
-    same step count, float-identical histories and best-val params (both
-    paths run the identical per-epoch computation; only the early-stop
-    bookkeeping moved on device)."""
+    """The whole-fit scan must reproduce the host loop EXACTLY on the
+    stored-trace workloads: same early-stop epoch count, same step count,
+    float-identical histories and best-val params (both programs run the
+    one epoch function; only the driver differs)."""
     for name, (params, data, kw) in _trace_runs().items():
         fused = training.train(params, data, ae.recon_loss, **kw)
-        loop = training.train_epochwise(params, data, ae.recon_loss, **kw)
+        loop = _epochwise(params, data, ae.recon_loss, **kw)
         assert fused.epochs_run == loop.epochs_run, name
         assert fused.steps_run == loop.steps_run, name
         np.testing.assert_allclose(fused.train_loss, loop.train_loss,
@@ -205,7 +214,7 @@ def test_fused_matches_epochwise_early_stop():
     params, data = _toy(n=64, d=4)
     kw = dict(batch_size=16, max_epochs=50, patience=3, lr=0.0, seed=0)
     fused = training.train(params, data, ae.recon_loss, **kw)
-    loop = training.train_epochwise(params, data, ae.recon_loss, **kw)
+    loop = _epochwise(params, data, ae.recon_loss, **kw)
     assert fused.epochs_run == loop.epochs_run == 1 + 3
     assert _max_leaf_diff(fused.params, loop.params) == 0.0
 
@@ -229,12 +238,29 @@ def test_fused_lanes_match_epochwise_lanes():
         assert _max_leaf_diff(f.params, l.params) < 1e-6, i
 
 
+def test_train_is_lane_zero_of_train_lanes():
+    """A single fit is a lane of one: ``train`` returns exactly what
+    ``train_lanes`` returns for the same spec, bit for bit."""
+    params, data = _toy(n=150, d=6, seed=4)
+    kw = dict(batch_size=32, max_epochs=6, patience=3)
+    solo = training.train(params, data, ae.recon_loss, seed=4, **kw)
+    (lane,) = training.train_lanes([training.LaneSpec(params, data, 4)],
+                                   ae.recon_loss, **kw)
+    assert (solo.epochs_run, solo.steps_run) == (lane.epochs_run,
+                                                 lane.steps_run)
+    assert solo.train_loss == lane.train_loss
+    assert solo.val_loss == lane.val_loss
+    for a, b in zip(jax.tree.leaves(solo.params),
+                    jax.tree.leaves(lane.params)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_fused_fit_is_single_dispatch(monkeypatch):
-    """<=1 host sync per fit: the whole fit goes through exactly one call
-    of the fused engine (the epoch loop lives inside the jitted scan)."""
+    """<=1 host sync per fit: a single fit goes through exactly one call
+    of the scan program (the epoch loop lives inside the jitted scan)."""
     params, data = _toy(n=120, d=5)
     calls = []
-    real = training.get_fit_engine
+    real = training.get_lanes_fit_engine
 
     def spy(loss_fn, *, lr=1e-3):
         engine = real(loss_fn, lr=lr)
@@ -244,7 +270,7 @@ def test_fused_fit_is_single_dispatch(monkeypatch):
             return engine(*a, **k)
         return wrapped
 
-    monkeypatch.setattr(training, "get_fit_engine", spy)
+    monkeypatch.setattr(training, "get_lanes_fit_engine", spy)
     r = training.train(params, data, ae.recon_loss, batch_size=32,
                        max_epochs=9, patience=99, seed=0)
     assert r.epochs_run == 9
